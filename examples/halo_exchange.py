@@ -21,8 +21,6 @@ import numpy as np
 
 
 def main() -> int:
-    from _platform import force_cpu_if_requested
-    force_cpu_if_requested()
     import jax
     import jax.numpy as jnp
 

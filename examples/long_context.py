@@ -17,11 +17,7 @@ Run (virtual 8-device mesh):
 import os
 import time
 
-import jax  # noqa: F401  (imported before any op)
-
-from _platform import force_cpu_if_requested
-
-force_cpu_if_requested()
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P

@@ -13,11 +13,7 @@ Run (virtual 8-device mesh):
 import os
 import tempfile
 
-import jax  # noqa: F401  (imported before any op)
-
-from _platform import force_cpu_if_requested
-
-force_cpu_if_requested()
+import jax
 import jax.numpy as jnp
 import numpy as np
 

@@ -47,14 +47,17 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 # collective primitives -> canonical op name (jax names reduce_scatter's
-# primitive "reduce_scatter"; lax.psum_scatter builds it)
+# primitive "reduce_scatter"; lax.psum_scatter builds it; under
+# shard_map's VMA typing a psum of a varying value binds psum_invariant)
 _COLL_PRIMS = {
     "psum": "psum",
+    "psum_invariant": "psum",
     "pmin": "pmin",
     "pmax": "pmax",
     "ppermute": "ppermute",
     "all_to_all": "all_to_all",
     "all_gather": "all_gather",
+    "all_gather_invariant": "all_gather",
     "reduce_scatter": "reduce_scatter",
     "psum_scatter": "reduce_scatter",
 }

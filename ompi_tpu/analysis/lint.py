@@ -123,7 +123,6 @@ _CL001_ENGINE_SUFFIXES = (
     "ompi_tpu/parallel/overlap.py",
     "ompi_tpu/ops/collective_matmul.py",
     "ompi_tpu/serving/fused.py",
-    "ompi_tpu/jaxcompat.py",
     "ompi_tpu/tools/coll_tune.py",
 )
 

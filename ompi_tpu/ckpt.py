@@ -184,7 +184,8 @@ def _check_global_shapes(path: str, like: Any, rank: int = 0) -> None:
     tu = jax.tree_util
     mismatched = []
     try:
-        meta = _ocp().StandardCheckpointer().metadata(path)
+        # StepMetadata: the saved leaves are under item_metadata.tree
+        meta = _ocp().StandardCheckpointer().metadata(path).item_metadata.tree
         want = {tu.keystr(kp): tuple(x.shape)
                 for kp, x in tu.tree_leaves_with_path(like)
                 if hasattr(x, "shape")}

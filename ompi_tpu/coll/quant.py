@@ -153,12 +153,13 @@ def _reduce_scatter_quant(chunks, axis: str, n: int, block: int,
 
 def _all_gather_quant(x, axis: str, n: int, block: int, scale_dtype):
     """x: (C,) f32 with C % block == 0 -> (n, C) f32: row j = device j's
-    vector, moved over the wire as int8+scales."""
-    from jax import lax
+    vector, moved over the wire as int8+scales (replicated over ``axis``
+    on exit, so an allreduce ending here is typed invariant)."""
+    from ..parallel.collectives import all_gather_invariant
 
     q, s = quantize_blocks(x, block, scale_dtype)
-    qg = lax.all_gather(q, axis, axis=0)              # (n, C) int8
-    sg = lax.all_gather(s, axis, axis=0)              # (n, C/block)
+    qg = all_gather_invariant(q, axis, axis=0)        # (n, C) int8
+    sg = all_gather_invariant(s, axis, axis=0)        # (n, C/block)
     return dequantize_blocks(qg, sg, block)
 
 

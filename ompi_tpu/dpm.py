@@ -36,6 +36,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from .comm import Communicator, Group
+from .control.launch import TPU_PROCESS_VARS
 
 _SPAWN_CID_BASE = 1 << 44        # intercomm cids for spawn, out of all ranges
 _PORT_CID_BASE = 1 << 45         # intercomm cids for connect/accept
@@ -72,7 +73,8 @@ def spawn(comm: Communicator, command: Sequence[str], maxprocs: int,
                 # new job placement the caller controls via env_extra
                 # (≙ the MPI_Info keys of MPI_Comm_spawn) — inheriting the
                 # parent's cpuset would pile every child onto one core
-                env.pop("TPU_VISIBLE_DEVICES", None)
+                for k in TPU_PROCESS_VARS:
+                    env.pop(k, None)
                 env.pop("OMPI_TPU_BIND_CPUS", None)
                 if env_extra:
                     env.update(env_extra)

@@ -45,7 +45,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import importlib
 
-from .. import jaxcompat as _compat, trace
+from .. import trace
 from ..parallel.mesh import make_mesh
 
 # the parallel package re-exports the reshard FUNCTION under the same
@@ -212,7 +212,7 @@ class ShadowStore:
             n, ax = self.n, self.axis
             perm = [(i, (i + 1) % n) for i in range(n)]
             # comm-lint: disable=CL001 the +1 ring shift IS the shadow-replication scheme (each device parks its block on its ring neighbor), not an engine-dispatchable collective; wire bytes attributed at the eager boundary via note_ppermute (coll ft_shadow) in refresh()
-            fn = jax.jit(_compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda v: lax.ppermute(v, ax, perm=perm),  # comm-lint: disable=CL001 same ring shift, kernel body
                 mesh=self.mesh, in_specs=P(ax), out_specs=P(ax)))
             self._shift_fns[key] = fn

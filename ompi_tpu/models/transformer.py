@@ -121,7 +121,7 @@ class Config:
 def flagship_config(seq: int = 2048) -> Config:
     """The single-chip flagship: sized so the MXU saturates (d_model 2048
     ≥ the 128×128 systolic tile by 16×, head_dim 128 = one lane tile,
-    d_ff 4×) and the Pallas flash path carries attention. ~440 M params —
+    d_ff 4×) and the Pallas flash path carries attention. ~470 M params —
     fp32 master + Adam moments ≈ 5.3 GB, activations with "dots" remat fit
     a 16 GB v5e at batch 4 × seq 2048."""
     return Config(vocab=32768, d_model=2048, n_layers=6, n_heads=16,
@@ -424,6 +424,29 @@ def _layer_apply_fused(x: jax.Array, layer: Dict, cfg: Config,
     return x + down, jnp.zeros((), jnp.float32)
 
 
+def _flash_attn(q, k, v, cfg: Config, mesh: Optional[Mesh]) -> jax.Array:
+    """Causal Pallas flash attention, fwd + bwd.  Mosaic kernels cannot be
+    partitioned by GSPMD, so on a mesh every device runs the kernel on its
+    own block — batch over dp, heads over tp — inside a shard_map."""
+    from ..ops.attention import flash_mha
+
+    def attend(q, k, v):
+        return flash_mha(q, k, v, True, None, cfg.attn_block,
+                         cfg.attn_block, None, cfg.attn_bwd_block,
+                         cfg.attn_bwd_block)
+
+    if mesh is None:
+        return attend(q, k, v)
+    names = mesh.axis_names
+    spec = P("dp" if "dp" in names else None, None,
+             "tp" if "tp" in names else None, None)
+    # check_vma off: the kernel body carries no VMA types (as ring.py's
+    # Pallas block); the block-local program has no collective to type
+    # comm-lint: disable=CL001 block-local flash kernel: no collective inside
+    return jax.shard_map(attend, mesh=mesh, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def _attn_apply(x: jax.Array, layer: Dict, cfg: Config,
                 mesh: Optional[Mesh]) -> jax.Array:
     """Attention half of the decoder layer, residual included."""
@@ -444,10 +467,7 @@ def _attn_apply(x: jax.Array, layer: Dict, cfg: Config,
                              head_axis="tp" if "tp" in mesh.axis_names
                              else None)
     elif cfg.attn == "flash":
-        from ..ops.attention import flash_mha
-        att = flash_mha(q, k, v, True, None,           # Pallas fwd + bwd
-                        cfg.attn_block, cfg.attn_block, None,
-                        cfg.attn_bwd_block, cfg.attn_bwd_block)
+        att = _flash_attn(q, k, v, cfg, mesh)
     else:
         att = attention_reference(q, k, v, causal=True)
     att = att.reshape(b, s, cfg.n_heads * cfg.head_dim)
@@ -649,7 +669,6 @@ def _quant_grad_sync(cfg: Config, mesh: Mesh):
     would silently undo tp/sp parameter sharding — refuse instead, matching
     the loss_chunk contract above."""
     from ..coll.quant import psum_quant
-    from ..jaxcompat import shard_map
 
     if "dp" not in mesh.axis_names:
         raise ValueError(
@@ -667,7 +686,9 @@ def _quant_grad_sync(cfg: Config, mesh: Mesh):
 
     def local(params, tokens):
         # mesh=None inside: the model sees only its batch shard; the one
-        # cross-shard exchange is the gradient sync below
+        # cross-shard exchange is the gradient sync below (the cast keeps
+        # autodiff from summing the grads over dp itself)
+        params = lax.pcast(params, "dp", to="varying")
         loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg, None)
         grads = jax.tree.map(
             lambda g: psum_quant(g, "dp", n, avg=True,
@@ -676,8 +697,8 @@ def _quant_grad_sync(cfg: Config, mesh: Mesh):
         return lax.pmean(loss, "dp"), grads
 
     # comm-lint: disable=CL001 the quant grad-sync tier: its comm is psum_quant (coll/quant engine) plus the waived scalar pmean
-    return shard_map(local, mesh=mesh, in_specs=(P(), data_spec),
-                     out_specs=(P(), P()))
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(), data_spec),
+                         out_specs=(P(), P()))
 
 
 def make_train_step(cfg: Config, mesh: Optional[Mesh] = None,
@@ -777,4 +798,5 @@ def make_train_step(cfg: Config, mesh: Optional[Mesh] = None,
             numerics.record_step(loss=float(out[2]))
         return out
 
+    timed_step.jitted = jstep       # for .lower()/.compile() inspection
     return init_opt, timed_step

@@ -26,8 +26,9 @@ Three entry points:
     O(seq²) score tensor, which is what lets the flagship train step keep
     long sequences on the MXU at high utilization.
 
-Interpret mode (``interpret=True``) runs the same kernels on CPU for tests;
-on TPU backends the default is the compiled path.
+Interpret mode (``interpret=True``) runs the same kernels on CPU for tests.
+Left unset, it is the compiled path on TPU and the interpreter on CPU; any
+other backend raises rather than guessing.
 """
 
 from __future__ import annotations
@@ -45,7 +46,15 @@ NEG_INF = -1e30
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on TPU, interpreted on the CPU (the test path); any
+    other backend cannot run these Mosaic kernels at all."""
+    backend = jax.default_backend()
+    if backend in ("tpu", "cpu"):
+        return backend == "cpu"
+    raise RuntimeError(
+        f"Pallas flash kernels need a TPU or the CPU interpreter; backend "
+        f"{backend!r} is neither (pass interpret=True to force the "
+        f"interpreter)")
 
 
 def check_tpu_block(block, array_shape, what: str = "block",

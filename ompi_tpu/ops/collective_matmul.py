@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jaxcompat import shard_map
 
 
 def ring_allgather_matmul_local(x, w, axis: str, n: int, *,
@@ -148,10 +147,10 @@ def _build_allgather_matmul(mesh: Mesh, axis: str, w_spec: P, reverse: bool,
     # The output is value-replicated over `axis` (every rank fills all n
     # blocks) but provenance-varying (it flowed through ppermute), so the
     # static VMA check can't prove replication — disable it here.
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(x_spec, w_spec),
-                             out_specs=out_spec,
-                             check_vma=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(x_spec, w_spec),
+                                 out_specs=out_spec,
+                                 check_vma=False))
 
 
 def allgather_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, axis: str,
@@ -288,9 +287,9 @@ def _build_matmul_rs(mesh: Mesh, axis: str, bidir: bool,
     else:
         in_specs = (P(None, axis), P(axis, None))
         out_spec = P(axis, None)
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=in_specs,
-                             out_specs=out_spec))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=in_specs,
+                                 out_specs=out_spec))
 
 
 def matmul_reduce_scatter(x: jax.Array, w: jax.Array, mesh: Mesh,

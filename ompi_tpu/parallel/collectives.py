@@ -34,9 +34,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax._src.lax.parallel import all_gather_invariant  # noqa: F401
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import jaxcompat as _compat, trace
+from .. import trace
 from ..core import var as _var
 from ..op import MAX, MIN, SUM, Op
 
@@ -52,6 +53,12 @@ _var.register(
 # ---------------------------------------------------------------------------
 # named-axis primitives (for use inside shard_map) — thin, explicit wrappers
 # ---------------------------------------------------------------------------
+#
+# ``all_gather_invariant`` (re-exported above; jax 0.9 has no public alias)
+# is the gather whose result shard_map's VMA typing knows is replicated over
+# the gathered axis: use it wherever a gather ends an allreduce or a
+# relayout whose out_specs drop that axis.  ``lax.all_gather`` keeps the
+# result device-varying.
 
 
 def psum(x, axis: str):
@@ -346,7 +353,7 @@ class DeviceComm:
         return fn
 
     def _shard_map(self, fn, in_specs, out_specs):
-        return jax.jit(_compat.shard_map(fn, mesh=self.mesh,
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh,
                                          in_specs=in_specs,
                                          out_specs=out_specs))
 
@@ -1239,7 +1246,7 @@ class DeviceComm:
                 out0 = jnp.zeros((rr, out_cap + S) + e_shape, xs.dtype)
                 # the body's all_to_all makes the carry VARYING over the
                 # mesh axis; the zeros init must match (shard_map VMA)
-                out0 = _compat.pcast(out0, (self.axis,), to="varying")
+                out0 = lax.pcast(out0, self.axis, to="varying")
                 out, _ = lax.scan(body, out0,
                                   jnp.arange(k, dtype=jnp.int32))
                 return out[:, :out_cap]

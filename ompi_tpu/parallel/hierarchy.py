@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jaxcompat import shard_map
+from .collectives import all_gather_invariant
 from .mesh import classify_axes
 
 # classify_axes is re-exported here as the PUBLIC topology-inference
@@ -53,7 +53,7 @@ def hierarchical_psum(x, inner: str, outer: str):
     x, orig = _pad_to_inner(x, inner)
     scattered = lax.psum_scatter(x, inner, scatter_dimension=0, tiled=True)
     reduced = lax.psum(scattered, outer)
-    out = lax.all_gather(reduced, inner, axis=0, tiled=True)
+    out = all_gather_invariant(reduced, inner, axis=0, tiled=True)
     return out[:orig] if out.shape[0] != orig else out
 
 
@@ -70,7 +70,7 @@ def hierarchical_psum_quant(x, inner: str, outer: str, n_outer: int,
     x, orig = _pad_to_inner(x, inner)
     scattered = lax.psum_scatter(x, inner, scatter_dimension=0, tiled=True)
     reduced = psum_quant(scattered, outer, n_outer, block=block)
-    out = lax.all_gather(reduced, inner, axis=0, tiled=True)
+    out = all_gather_invariant(reduced, inner, axis=0, tiled=True)
     return out[:orig] if out.shape[0] != orig else out
 
 
@@ -97,8 +97,8 @@ def hierarchical_allreduce(x: jax.Array, mesh: Mesh, inner: str, outer: str
         traffic.note_hierarchical(mesh, inner, outer,
                                   x.nbytes // max(ni * no, 1))
 
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=spec,
-                           out_specs=spec))
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=spec,
+                               out_specs=spec))
     return fn(x)
 
 

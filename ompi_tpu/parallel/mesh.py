@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import var as _var
-from ..jaxcompat import auto_axis_types
 
 # conventional axis names, outer→inner (DCN-most → ICI-most)
 STANDARD_AXES = ("dp", "fsdp", "pp", "ep", "sp", "tp")
@@ -71,7 +70,7 @@ def make_mesh(axes: Dict[str, int],
         raise ValueError(
             f"mesh {dict(zip(names, sizes))} needs {total} devices, "
             f"have {len(devs)}")
-    auto = auto_axis_types(len(names))
+    auto = {"axis_types": (jax.sharding.AxisType.Auto,) * len(names)}
     if devices is None:
         return jax.make_mesh(tuple(sizes), tuple(names), **auto)
     return Mesh(np.asarray(devs).reshape(sizes), tuple(names), **auto)

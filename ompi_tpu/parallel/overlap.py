@@ -53,7 +53,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import trace
 from ..core import var as _var
-from ..jaxcompat import shard_map
 
 GRAD_SYNC_MODES = ("perleaf", "bucketed", "unsynced")
 
@@ -220,7 +219,12 @@ def _make_bucket_tag(shapes, dtypes, arm: str, axis, n: int,
     the backward pass has produced every cotangent in the bucket — the
     overlap point.  ``axis`` may be a tuple of mesh axis names (the
     dpo×dp sync domain); ``levels`` is ``(inner, outer, n_outer)`` for
-    the hier arms."""
+    the hier arms.
+
+    Under shard_map's VMA typing the tag is the leaves' replicated ->
+    per-device cast: the forward marks them varying over ``axis`` (so
+    autodiff inserts no psum of its own) and the backward's allreduce is
+    that cast's transpose, returning replicated cotangents."""
     sizes = tuple(int(np.prod(s)) if s else 1 for s in shapes)
 
     def sync(cts):
@@ -253,10 +257,10 @@ def _make_bucket_tag(shapes, dtypes, arm: str, axis, n: int,
 
     @jax.custom_vjp
     def tag(group):
-        return group
+        return lax.pcast(group, axis, to="varying")
 
     def fwd(group):
-        return group, None
+        return tag(group), None
 
     def bwd(_, cts):
         return (sync(cts),)
@@ -358,6 +362,11 @@ def make_grad_sync(mode: str, mesh: Mesh, local_loss: Callable,
         plane, levels = None, None
 
     def local(params, batch):
+        if mode != "bucketed":
+            # differentiate this shard's own copy, so grads stay the
+            # per-device partials until the sync below (the bucket tags
+            # do this cast themselves)
+            params = lax.pcast(params, sync_axis, to="varying")
         if mode == "bucketed":
             leaves, _ = jax.tree_util.tree_flatten(params)
             plan = bucket_plan(leaves, nb)
@@ -382,8 +391,11 @@ def make_grad_sync(mode: str, mesh: Mesh, local_loss: Callable,
                     lambda g: lax.pmean(g, sync_axis), grads)
         return lax.pmean(loss, sync_axis), grads
 
-    inner = shard_map(local, mesh=mesh, in_specs=(P(), data_spec),
-                      out_specs=(P(), P()))
+    # unsynced returns per-device grads under a replicated out_spec: a
+    # measurement-only lie the VMA check would (rightly) refuse
+    inner = jax.shard_map(local, mesh=mesh, in_specs=(P(), data_spec),
+                          out_specs=(P(), P()),
+                          check_vma=(mode != "unsynced"))
 
     def _note_traffic(grads):
         # ring-allreduce model of the sync over the (possibly two-tier)

@@ -35,7 +35,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import trace
-from ..jaxcompat import shard_map
 
 
 def stack_stage_params(layer_params: list, n_stages: int):
@@ -135,7 +134,7 @@ def pipeline(stage_fn: Callable[[Any, jax.Array], jax.Array],
     par_spec = jax.tree.map(lambda _: P(axis), stage_params)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(par_spec, mb_spec),
         out_specs=mb_spec, check_vma=False)
     def run(params, mbs):

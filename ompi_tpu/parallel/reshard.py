@@ -71,9 +71,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .. import jaxcompat as _compat, trace
+from .. import trace
 from ..core import var as _var
-from .collectives import all_to_all_axis
+from .collectives import all_gather_invariant, all_to_all_axis
 from .mesh import classify_axes
 
 _var.register("reshard", "", "peak_factor", 2.0, type=float, level=3,
@@ -552,7 +552,9 @@ class Resharder:
                 ax, d = step.axes[0], step.dim
 
                 def inner(xs):
-                    return lax.all_gather(xs, ax, axis=d, tiled=True)
+                    # the gathered dim is replicated over ``ax`` on exit:
+                    # the Varying -> Invariant gather types it so
+                    return all_gather_invariant(xs, ax, axis=d, tiled=True)
             elif step.op == "slice":
                 ax, d = step.axes[0], step.dim
                 m = sizes[ax]
@@ -568,9 +570,9 @@ class Resharder:
                     return lax.ppermute(xs, axes, perm=perm)
             else:                   # pragma: no cover — grammar is closed
                 raise ReshardError(f"unknown plan op {step.op!r}")
-            return jax.jit(_compat.shard_map(inner, mesh=mesh,
-                                             in_specs=step.in_spec,
-                                             out_specs=step.out_spec))
+            return jax.jit(jax.shard_map(inner, mesh=mesh,
+                                         in_specs=step.in_spec,
+                                         out_specs=step.out_spec))
         return self._compiled(key, build)
 
     # -- decision + audit ----------------------------------------------

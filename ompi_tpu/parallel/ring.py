@@ -25,7 +25,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jaxcompat import pcast, shard_map, typeof_vma
 
 NEG_INF = -1e30
 
@@ -146,8 +145,8 @@ def _build_ring(mesh: Mesh, axis: str, causal: bool, scale: float,
         def vary_all(x):
             if block_impl == "pallas":     # vma tracking is off (see below)
                 return x
-            missing = tuple(a for a in axes if a not in typeof_vma(x))
-            return pcast(x, missing, to="varying") if missing else x
+            missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+            return lax.pcast(x, missing, to="varying") if missing else x
 
         o0 = vary_all(jnp.zeros_like(qf))
         m0 = vary_all(jnp.full(qf.shape[:2], NEG_INF, qf.dtype))
@@ -161,9 +160,9 @@ def _build_ring(mesh: Mesh, axis: str, causal: bool, scale: float,
     # lowering can't yet propagate varying-manual-axes through its internal
     # dynamic_slice (jax suggests this exact workaround).
     # comm-lint: disable=CL001 ring attention is a leaf SPMD kernel: its only comm is the waived ppermute ring above, verified statically by analysis.commgraph
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec,
-                             check_vma=(block_impl != "pallas")))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                                 out_specs=spec,
+                                 check_vma=(block_impl != "pallas")))
 
 
 def attention_reference(q, k, v, causal: bool = False,

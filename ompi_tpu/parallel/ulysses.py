@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..jaxcompat import shard_map
 from .ring import attention_reference
 
 
@@ -72,5 +71,5 @@ def _build_ulysses(mesh: Mesh, axis: str, causal: bool,
 
     spec = P(None, axis, None, None)
     # comm-lint: disable=CL001 leaf SPMD kernel: only comm is the waived alltoall pair, statically verified by analysis.commgraph
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                                 out_specs=spec))

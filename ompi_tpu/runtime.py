@@ -26,6 +26,7 @@ ranks) are fully independent.
 
 from __future__ import annotations
 
+import os
 import threading
 from typing import Callable, Dict, List, Optional
 
@@ -292,6 +293,22 @@ def finalize() -> None:
     if _process_ctx is not None:
         _process_ctx.finalize()
         _process_ctx = None
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first
+    compile and return its directory.  A ``JAX_COMPILATION_CACHE_DIR``
+    from the environment (which jax reads itself) wins untouched;
+    otherwise the cache lives at ``<checkout>/.jax_cache`` — a fixed
+    path, since the path is part of the cache key."""
+    import jax
+
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", d)
+    return d
 
 
 _job_seq = 0

@@ -36,7 +36,6 @@ class PagedKVCache:
                  n_pages: int = 64, page_size: int = 16,
                  max_seqs: int = 8, max_pages_per_seq: Optional[int] = None,
                  dtype=None) -> None:
-        import jax
         import jax.numpy as jnp
 
         if n_heads % dc.n:
@@ -59,11 +58,13 @@ class PagedKVCache:
         self.dtype = dtype if dtype is not None else jnp.float32
         shape = (dc.n, self.n_pages, self.page_size, self.heads_local,
                  self.head_dim)
-        zeros = jnp.zeros(shape, self.dtype)
+        # one buffer per pool: the page writes donate them, and a
+        # device_put onto the device a shared zeros already lives on
+        # (tp=1) would hand every pool the same buffer
         sh = dc.sharding()
-        self.k: List = [jax.device_put(zeros, sh)
+        self.k: List = [jnp.zeros(shape, self.dtype, device=sh)
                         for _ in range(self.n_layers)]
-        self.v: List = [jax.device_put(zeros, sh)
+        self.v: List = [jnp.zeros(shape, self.dtype, device=sh)
                         for _ in range(self.n_layers)]
         # host-side page bookkeeping (page 0 reserved as scratch)
         self._free: List[int] = list(range(self.n_pages - 1, 0, -1))

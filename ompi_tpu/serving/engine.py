@@ -87,8 +87,8 @@ def _j_qkv(x, norm_w, wqkv, pos, head_dim, base):
 def _j_page_write(kp, vp, k_new, v_new, page_idx, offset):
     """Scatter one k/v row per batch slot into its page — donated, so
     the pools update in place and cache data never visits the host."""
-    kp = kp.at[:, page_idx, offset].set(k_new)
-    vp = vp.at[:, page_idx, offset].set(v_new)
+    kp = kp.at[:, page_idx, offset].set(k_new.astype(kp.dtype))
+    vp = vp.at[:, page_idx, offset].set(v_new.astype(vp.dtype))
     return kp, vp
 
 

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..jaxcompat import shard_map
 from ..models.transformer import _rms_norm, decode_attention, rope_rows
 from ..ops.collective_matmul import (ring_allgather_matmul_local,
                                      ring_matmul_reduce_scatter_local)
@@ -162,6 +161,6 @@ def build_fused_decode(mesh, axis: str, n_layers: int, head_dim: int,
     # outputs are provenance-varying (they flowed through ppermute), so
     # the static VMA check can't type them — same waiver as the train
     # collective-matmul builders
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False),
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False),
                    donate_argnums=(8, 9))

@@ -38,7 +38,6 @@ from ompi_tpu.analysis.commgraph import (  # noqa: E402
     from_reshard_plan,
     verify,
 )
-from ompi_tpu.jaxcompat import shard_map  # noqa: E402
 from ompi_tpu.parallel import make_mesh, overlap  # noqa: E402
 from ompi_tpu.parallel.reshard import Resharder, compile_plan  # noqa: E402
 from ompi_tpu.parallel.ring import ring_attention  # noqa: E402
@@ -92,8 +91,8 @@ class TestExtract:
         def prog(x):
             def local(v):
                 return lax.psum(v.sum(), "dp"), lax.psum(v, "dp")
-            return shard_map(local, mesh=dp8, in_specs=(P("dp"),),
-                             out_specs=(P(), P()))(x)
+            return jax.shard_map(local, mesh=dp8, in_specs=(P("dp"),),
+                                 out_specs=(P(), P()))(x)
 
         x = jnp.ones((8, 4), jnp.float32)
         g = extract(prog, x)
@@ -105,8 +104,8 @@ class TestExtract:
 
     def test_graph_bookkeeping(self, dp8):
         def prog(x):
-            return shard_map(lambda v: lax.psum(v, "dp"), mesh=dp8,
-                             in_specs=(P("dp"),), out_specs=P())(x)
+            return jax.shard_map(lambda v: lax.psum(v, "dp"), mesh=dp8,
+                                 in_specs=(P("dp"),), out_specs=P())(x)
 
         g = extract(prog, jnp.ones((8,), jnp.float32), source="bk")
         assert g.source == "bk"
@@ -157,8 +156,8 @@ class TestChecks:
                                 lambda u: lax.psum(u, "dp"),
                                 lambda u: lax.ppermute(u, "dp", ring),
                                 v)
-            return shard_map(local, mesh=dp8, in_specs=(P("dp"),),
-                             out_specs=P("dp"), check_vma=False)(x)
+            return jax.shard_map(local, mesh=dp8, in_specs=(P("dp"),),
+                                 out_specs=P("dp"), check_vma=False)(x)
 
         g = extract(prog, jnp.ones((8,), jnp.float32))
         assert g.divergent_conds
@@ -171,8 +170,8 @@ class TestChecks:
                                 lambda u: lax.psum(u, "dp"),
                                 lambda u: lax.psum(u * 2.0, "dp"),
                                 v)
-            return shard_map(local, mesh=dp8, in_specs=(P("dp"),),
-                             out_specs=P())(x)
+            return jax.shard_map(local, mesh=dp8, in_specs=(P("dp"),),
+                                 out_specs=P())(x)
 
         g = extract(prog, jnp.ones((8,), jnp.float32))
         assert not g.divergent_conds
@@ -196,8 +195,8 @@ class TestChecks:
                 def cond(c):
                     return jnp.logical_and(c[0] < 64, c[1].sum() > 1e-3)
                 return lax.while_loop(cond, body, (0, v))[1]
-            return shard_map(local, mesh=dp8, in_specs=(P("dp"),),
-                             out_specs=P("dp"), check_vma=False)(x)
+            return jax.shard_map(local, mesh=dp8, in_specs=(P("dp"),),
+                                 out_specs=P("dp"), check_vma=False)(x)
 
         g = extract(prog, jnp.ones((8,), jnp.float32))
         psums = [r for r in g.records if r.op == "psum"]

@@ -1,0 +1,95 @@
+"""Main-path programs compiled for a described v5e chip (no chip needed).
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached: these tests catch what interpret mode cannot (an
+unlowerable Pallas block, a kernel over its VMEM budget, a collective the
+partitioner refuses) at real widths, at no chip time.  Nothing runs, so
+they say nothing about results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process may hold libtpu, and a module that decides at import whether
+its tests exist gives pytest-xdist workers different collections.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from ompi_tpu.ops.attention import flash_attention_partials, flash_mha
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_flash_mha_fwd_bwd_flagship_shape(one_chip):
+    shape = (1, 2048, 16, 128)            # flagship_config per sequence
+
+    def loss(q, k, v):
+        o = flash_mha(q, k, v, causal=True, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    args = [_sds(shape, jnp.bfloat16, one_chip)] * 3
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    # forward + dq + dk/dv kernels, all compiled to Mosaic
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+def test_flash_partials_ring_shard(one_chip):
+    # one ring hop of the flagship over 4 sequence shards: (b*h, s/4, d)
+    shape = (16, 512, 128)
+    args = [_sds(shape, jnp.bfloat16, one_chip)] * 3
+    fn = jax.jit(lambda q, k, v: flash_attention_partials(
+        q, k, v, causal=True, q_offset=512, kv_offset=0, interpret=False))
+    hlo = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_device_comm_collectives_four_chips(topo):
+    from ompi_tpu.op import SUM
+    from ompi_tpu.parallel import DeviceComm
+
+    mesh = Mesh(np.asarray(topo.devices), ("x",))
+    dc = DeviceComm(mesh, "x")
+    sh = NamedSharding(mesh, P("x"))
+    x = _sds((4, 4, 1 << 16), jnp.float32, sh)     # (R, R, b), 1 row/chip
+
+    def prog(v):
+        y = dc.allreduce(v, SUM)
+        return dc.alltoall(y)
+
+    compiled = jax.jit(prog).lower(x).compile()
+    hlo = compiled.as_text()
+    assert "all-reduce" in hlo and "all-to-all" in hlo
+    assert compiled.memory_analysis().argument_size_in_bytes == (
+        4 * (1 << 16) * 4)                          # one row per chip

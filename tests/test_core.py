@@ -196,6 +196,26 @@ def test_launcher_bind_env():
     assert "OMPI_TPU_BIND_CPUS" not in env2
 
 
+@pytest.mark.parametrize("chips,procs,per", [(1, "2,2,1", "1,1,1"),
+                                              (2, "1,2,1", "2,1,1")])
+def test_launcher_chip_env(chips, procs, per):
+    """--chips-per-rank hands each rank libtpu's multi-process contract:
+    its own chips, the bounds, and every local process's address."""
+    from ompi_tpu.control.launch import build_env
+    n = 4 // chips
+    envs = [build_env({}, rank=r, size=n, coord="h:1", job="j", mca=[],
+                      chips_per_rank=chips) for r in range(n)]
+    owned = [e["TPU_VISIBLE_CHIPS"].split(",") for e in envs]
+    assert sorted(sum(owned, [])) == ["0", "1", "2", "3"]
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {procs}
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {per}
+    addrs = envs[0]["TPU_PROCESS_ADDRESSES"].split(",")
+    assert len(addrs) == n
+    assert [f"localhost:{e['TPU_PROCESS_PORT']}" for e in envs] == addrs
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == [str(r)
+                                                      for r in range(n)]
+
+
 def test_interlib_declare_query_withdraw():
     """interlib (≙ ompi/interlib/interlib.c): co-resident runtimes declare
     themselves; the effective thread level is the most restrictive; query

@@ -393,3 +393,17 @@ class TestTpuTilingGuard:
         from ompi_tpu.ops.attention import check_tpu_block
         with pytest.raises(ValueError, match="different ranks"):
             check_tpu_block((1, 8), (4, 64, 1))
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", False), ("cpu", True),
+                                          ("gpu", None)])
+def test_default_interpret_never_guesses(monkeypatch, backend, want):
+    """Compiled on TPU, interpreted on the CPU, and any other backend is
+    refused rather than silently interpreted."""
+    from ompi_tpu.ops import attention
+    monkeypatch.setattr(attention.jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            attention._default_interpret()
+    else:
+        assert attention._default_interpret() is want

@@ -513,3 +513,19 @@ def test_pvars_in_spc(plane):
     assert 'ompi_tpu_perf_goodput_pct{rank="0",comm="world"} 90' in prom
     with pytest.raises(KeyError):
         perf.pvar_value("perf_banana")
+
+
+@pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197.0),
+                                       ("TPU v4", 275.0),
+                                       ("TPU v9 imaginary", None)])
+def test_bench_peak_table_keyed_by_device_kind(monkeypatch, kind, peak):
+    """bench.py's MFU denominator: exact device_kind keys; an unknown
+    chip is an error, never a default."""
+    import bench
+    monkeypatch.delenv("OMPI_TPU_PEAK_TFLOPS", raising=False)
+    dev = type("Dev", (), {"device_kind": kind})()
+    if peak is None:
+        with pytest.raises(ValueError, match="imaginary"):
+            bench._peak_tflops(dev)
+    else:
+        assert bench._peak_tflops(dev)[0] == peak

@@ -121,7 +121,6 @@ def test_allgather(dc):
 def test_psum_quant_inside_shard_map():
     """The gradient-sync primitive: psum_quant inside a user shard_map
     matches the exact psum to quantization tolerance."""
-    from ompi_tpu.jaxcompat import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh({"x": N})
@@ -130,8 +129,8 @@ def test_psum_quant_inside_shard_map():
     def body(x):
         return quant.psum_quant(x[0], "x", N, avg=True, block=64)[None]
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("x"),
-                           out_specs=P("x")))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("x"),
+                               out_specs=P("x")))
     out = np.asarray(jax.device_get(fn(jnp.asarray(host))))
     ref = host.mean(axis=0, dtype=np.float32)
     for row in out:

@@ -297,7 +297,6 @@ def test_traffic_conservation(plane):
 # -- satellite primitives: a2a pad exactness, strided ring_shift ------------
 
 def test_all_to_all_axis_pads_non_divisible(plane):
-    from ompi_tpu.jaxcompat import shard_map
     from ompi_tpu.parallel.collectives import all_to_all_axis
     mesh = _mesh(4)
     host = np.arange(4 * 6, dtype=np.float32).reshape(4, 6)
@@ -306,8 +305,8 @@ def test_all_to_all_axis_pads_non_divisible(plane):
     def f(xs):
         return all_to_all_axis(xs, "x", split_dim=1, concat_dim=0)
 
-    y = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x", None),
-                          out_specs=P("x", None)))(x)
+    y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x", None),
+                              out_specs=P("x", None)))(x)
     got = np.asarray(jax.device_get(y))
     # reference: each local row pads 6 -> 8 cols, peer p receives cols
     # [2p, 2p+2); device p's output stacks every source's block
