@@ -1789,12 +1789,11 @@ def run_goodput_probe(platform: str) -> None:
     plane.  With perf + trace live, trains the grad-sync step config on
     the dp mesh through three arms and converts the arm deltas into a
     measured goodput split (run_gradsync's floor methodology): exposed
-    comm = t_bucketed - floor, total comm = t_perleaf - floor.  The
-    bucketed arm's overlap spans feed the learned cost model through
-    the trace span sink, so the run also persists
-    PERF_LEDGER_<platform>.json.  Banks goodput/MFU/overlap-efficiency
-    columns into BENCH_r06.json; exits non-zero when any banked column
-    is missing/non-finite or the model learned nothing."""
+    comm = t_bucketed - floor, total comm = t_perleaf - floor.  The run
+    also persists PERF_LEDGER_<platform>.json (the cost model learns
+    only from timed dispatches; the jitted steps make none).  Banks
+    goodput/MFU/overlap-efficiency columns into BENCH_r06.json; exits
+    non-zero when any banked column is missing/non-finite."""
     import jax
     import jax.numpy as jnp
 
@@ -1838,24 +1837,6 @@ def run_goodput_probe(platform: str) -> None:
             times[arm] = dt
             print(f"goodput {arm:9s} step {dt * 1e3:8.2f} ms  "
                   f"loss {final:.4f}", flush=True)
-
-        # eager bucketed passes: inside the jitted step the sync inlines
-        # into the compiled program (vg sees a Tracer and records no
-        # spans) — eager vg calls are what hand the span sink its
-        # arm-attributed grad_sync:bucket samples for the cost model
-        from ompi_tpu.models.transformer import loss_fn
-        from ompi_tpu.parallel import overlap
-        cfg_b = Config(**base, grad_sync="bucketed")
-        eparams = init_params(jax.random.key(0), cfg_b)
-        evg = overlap.make_grad_sync(
-            "bucketed", mesh,
-            lambda p, t: loss_fn(p, t, cfg_b, None),
-            bucket_bytes=bucket_bytes)
-        etok = jnp.asarray(np.random.default_rng(0).integers(
-            0, base["vocab"], size=(batch, base["seq"] + 1)), jnp.int32)
-        for _ in range(3):
-            jax.block_until_ready(evg(eparams, etok))
-        del eparams, evg, etok
 
         floor = times["unsynced"]
         exposed = max(times["bucketed"] - floor, 0.0)
@@ -1922,10 +1903,6 @@ def run_goodput_probe(platform: str) -> None:
         if bad:
             raise SystemExit("goodput probe: unmeasured/non-finite "
                              f"columns {bad} (banked {gp})")
-        if buckets < 1:
-            raise SystemExit("goodput probe: cost model learned no "
-                             "buckets (overlap spans never reached the "
-                             "span sink)")
     finally:
         var.registry.clear_cli("perf_enabled")
         var.registry.reset_cache()

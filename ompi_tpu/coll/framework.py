@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional
 
+from .. import trace
 from ..core.component import frameworks
 from ..core.output import output, show_help
 
@@ -36,6 +37,17 @@ COLL_FUNCTIONS = [
     "neighbor_allgather", "neighbor_allgatherv", "neighbor_alltoall",
     "neighbor_alltoallv", "neighbor_alltoallw",
 ]
+
+# entry point -> its timed region, ``ompi.coll.<name>`` (trace.region):
+# from entry into the table's wrapper to its return
+_REGIONS: Dict[str, str] = {}
+
+
+def _region_name(name: str) -> str:
+    r = _REGIONS.get(name)
+    if r is None:
+        r = _REGIONS[name] = "ompi.coll." + name
+    return r
 
 
 class CollModule:
@@ -63,60 +75,62 @@ class CollTable:
         entries = object.__getattribute__(self, "_entries")
         if name in entries:
             fn = getattr(entries[name], name)
+            rname = _region_name(name)
 
             def counted(comm, *a, **kw):
-                if comm.revoked:
-                    from ..ft.ulfm import RevokedError
-                    raise RevokedError(comm.name)
-                spc = getattr(comm.ctx, "spc", None)
-                if spc is not None:
-                    spc.inc("collectives")
-                    if name == "barrier":
-                        spc.inc("barriers")
-                from .. import health, monitoring, numerics, perf, trace
-                if trace.enabled:
-                    # per-rank arrival marker: dispatch time is the entry
-                    # timestamp the fleet skew analysis keys on — every
-                    # rank records its OWN arrival, unlike the decision
-                    # audit which the driving rank emits once
-                    trace.instant(
-                        f"enter:{name}", "coll-enter", rank=comm.ctx.rank,
-                        args={"op": name, "comm": comm.cid,
-                              "nbytes": int(getattr(a[0], "nbytes", 0)
-                                            or 0) if a else 0})
-                if getattr(comm.ctx, "_monitor", None) is not None \
-                        or monitoring._hooks:
-                    # coll interposition (≙ coll/monitoring component);
-                    # PMPI-analog hooks fire even without an installed
-                    # Monitor, matching the osc events' gating
-                    monitoring.coll_event(comm, name, a[0] if a else None)
-                call = fn
-                if numerics.enabled:
-                    # payload fingerprints: wrap the innermost invocation
-                    # so pre/post stats surround the actual collective and
-                    # the xla audit's note_arm lands in the in-flight
-                    # probe entry (ompi_tpu/numerics/probes.py)
-                    def call(comm, *a, **kw):
-                        return numerics.probed_coll(fn, comm, name, a, kw)
-                if health.enabled:
-                    # flight recorder: hold a (cid, seq, signature) entry
-                    # while in flight so the watchdog/desync sentinel can
-                    # attribute a hang (ompi_tpu/health/registry.py)
-                    htok = health.coll_begin(comm, name, a, kw)
-                    try:
-                        if perf.enabled:
-                            # cost-model sample: dispatch timed; the arm
-                            # is annotated post-decision by coll/xla's
-                            # audit (perf.note_arm) — un-annotated
-                            # dispatches are dropped, and a raising
-                            # collective contributes nothing
-                            return perf.timed_coll(call, comm, name, a, kw)
-                        return call(comm, *a, **kw)
-                    finally:
-                        health.op_end(htok)
-                if perf.enabled:
-                    return perf.timed_coll(call, comm, name, a, kw)
-                return call(comm, *a, **kw)
+                with trace.region(rname):
+                    if comm.revoked:
+                        from ..ft.ulfm import RevokedError
+                        raise RevokedError(comm.name)
+                    spc = getattr(comm.ctx, "spc", None)
+                    if spc is not None:
+                        spc.inc("collectives")
+                        if name == "barrier":
+                            spc.inc("barriers")
+                    from .. import health, monitoring, numerics, perf
+                    if trace.enabled:
+                        # per-rank arrival marker: dispatch time is the entry
+                        # timestamp the fleet skew analysis keys on — every
+                        # rank records its OWN arrival, unlike the decision
+                        # audit which the driving rank emits once
+                        trace.instant(
+                            f"enter:{name}", "coll-enter", rank=comm.ctx.rank,
+                            args={"op": name, "comm": comm.cid,
+                                  "nbytes": int(getattr(a[0], "nbytes", 0)
+                                                or 0) if a else 0})
+                    if getattr(comm.ctx, "_monitor", None) is not None \
+                            or monitoring._hooks:
+                        # coll interposition (≙ coll/monitoring component);
+                        # PMPI-analog hooks fire even without an installed
+                        # Monitor, matching the osc events' gating
+                        monitoring.coll_event(comm, name, a[0] if a else None)
+                    call = fn
+                    if numerics.enabled:
+                        # payload fingerprints: wrap the innermost invocation
+                        # so pre/post stats surround the actual collective and
+                        # the xla audit's note_arm lands in the in-flight
+                        # probe entry (ompi_tpu/numerics/probes.py)
+                        def call(comm, *a, **kw):
+                            return numerics.probed_coll(fn, comm, name, a, kw)
+                    if health.enabled:
+                        # flight recorder: hold a (cid, seq, signature) entry
+                        # while in flight so the watchdog/desync sentinel can
+                        # attribute a hang (ompi_tpu/health/registry.py)
+                        htok = health.coll_begin(comm, name, a, kw)
+                        try:
+                            if perf.enabled:
+                                # cost-model sample: dispatch timed; the arm
+                                # is annotated post-decision by coll/xla's
+                                # audit (perf.note_arm) — un-annotated
+                                # dispatches are dropped, and a raising
+                                # collective contributes nothing
+                                return perf.timed_coll(call, comm, name, a, kw)
+                            return call(comm, *a, **kw)
+                        finally:
+                            health.op_end(htok)
+                    if perf.enabled:
+                        return perf.timed_coll(call, comm, name, a, kw)
+                    return call(comm, *a, **kw)
 
             return counted
         # nonblocking variants: i<name> falls back to eager execution wrapped

@@ -22,6 +22,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import trace
 from ..core import var as _var
 from ..core.component import Component, component
 from ..op import SUM, Op
@@ -464,9 +465,11 @@ class XlaModule(CollModule):
         event always matches the executed path.  Every device dispatch
         funnels through here exactly once: one decision-audit record per
         collective."""
-        pick, reason, chain = self._decide(coll, x, op, allowed)
-        self._audit(coll, x, op, pick, reason, chain, weights=weights,
-                    extra=extra)
+        with trace.region("ompi.coll.decide"):
+            pick, reason, chain = self._decide(coll, x, op, allowed)
+        with trace.region("ompi.coll.audit"):
+            self._audit(coll, x, op, pick, reason, chain, weights=weights,
+                        extra=extra)
         return pick
 
     def _decide(self, coll: str, x, op: Op, allowed) -> tuple:
@@ -510,8 +513,6 @@ class XlaModule(CollModule):
         f32 size the dispatch layer recorded is not what travels).
         When tracing is on: the full decision event with the precedence
         chain, feeding trace.explain_last."""
-        from .. import trace
-
         rows = max(x.shape[0], 1)
         nbytes = x.nbytes // rows
         wire = nbytes

@@ -250,6 +250,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="ompi_flash_fwd",
     )(qf, kf, vf)
     return jnp.moveaxis(out.reshape(b, h, s_q, d), 1, 2)
 
@@ -379,6 +380,7 @@ def flash_attention_partials(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="ompi_flash_partials",
     )(offs, q, k, v)
     return o, m[..., 0], l[..., 0]
 
@@ -595,6 +597,7 @@ def _flash_mha_bwd(causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="ompi_flash_bwd_dkv",
     )(qf, kf, vf, dof, lse3, delta)
 
     dqk = functools.partial(
@@ -615,6 +618,7 @@ def _flash_mha_bwd(causal, scale, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct((bh, s_q, d), qf.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="ompi_flash_bwd_dq",
     )(qf, kf, vf, dof, lse3, delta)
 
     unfold = lambda x, s: jnp.moveaxis(x.reshape(b, h, s, d), 1, 2)

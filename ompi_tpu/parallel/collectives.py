@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import collections
 import functools
-import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -323,23 +322,12 @@ class DeviceComm:
     def _compiled(self, key: tuple, build: Callable) -> Callable:
         fn = self._cache.get(key)
         if fn is None:
-            if trace.enabled:
-                # build() constructs + jits the program; XLA compiles
-                # lazily, so the first-execution compile lands inside
-                # whatever execution span surrounds the miss
-                t0 = time.perf_counter()
-                try:
-                    fn = build()
-                except BaseException:
-                    trace.record_span(f"build:{key[0]}", "compile", t0,
-                                      time.perf_counter(),
-                                      args={"key": repr(key),
-                                            "status": "error"})
-                    raise
-                trace.record_span(f"build:{key[0]}", "compile", t0,
-                                  time.perf_counter(),
-                                  args={"key": repr(key)})
-            else:
+            # build() constructs + jits the program; XLA compiles lazily,
+            # so the first-execution compile lands inside whatever region
+            # surrounds the miss (ompi.coll.launch)
+            with trace.region("ompi.coll.build", f"build:{key[0]}",
+                              "compile", args={"key": repr(key)}
+                              if trace.enabled else None):
                 fn = build()
             self._cache[key] = fn
             if self.spc is not None:
@@ -391,6 +379,7 @@ class DeviceComm:
             acc = op.fn(acc, xs[i])
         return acc
 
+    @trace.timed("ompi.coll.launch")
     def allreduce(self, x: jax.Array, op: Op = SUM) -> jax.Array:
         """Every rank's row ← op over all rows. (R,*e) → (R,*e)."""
         key = ("allreduce", op.name, x.shape, str(x.dtype))
@@ -431,6 +420,7 @@ class DeviceComm:
 
         return self._compiled(key, build)(x)
 
+    @trace.timed("ompi.coll.launch")
     def allgather(self, x: jax.Array) -> jax.Array:
         """(R, b, *e) → (R, R*b, *e): every row = concat of all rows.
 
@@ -483,6 +473,7 @@ class DeviceComm:
         r = ranks // n
         return [host[i // r] for i in range(ranks)]
 
+    @trace.timed("ompi.coll.launch")
     def reduce_scatter(self, x: jax.Array, op: Op = SUM) -> jax.Array:
         """(R, R*b, *e) → (R, b, *e): row i = op-reduced i-th block."""
         R = x.shape[0]
@@ -505,6 +496,7 @@ class DeviceComm:
 
         return self._compiled(key, build)(x)
 
+    @trace.timed("ompi.coll.launch")
     def alltoall(self, x: jax.Array) -> jax.Array:
         """(R, R, b, *e) → (R, R, b, *e): out[i, j] = in[j, i]."""
         R = x.shape[0]
@@ -1144,6 +1136,7 @@ class DeviceComm:
                 "scan_steps": int(-(-cap // slice_cap)),
                 "out_cap": int(out_cap)}
 
+    @trace.timed("ompi.coll.launch")
     def alltoallv_from_rows(self, x: jax.Array, counts,
                             slice_cap: Optional[int] = None
                             ) -> Tuple[jax.Array, list]:

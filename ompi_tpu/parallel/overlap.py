@@ -33,9 +33,9 @@ Observability: one ``trace.decision("grad_sync", ...)`` per bucket per
 build (``explain_last("grad_sync")`` names the chosen arm + bucket
 size), pvars ``grad_bucket_count`` / ``grad_bucket_bytes`` (read-through
 from :mod:`ompi_tpu.spc`), and — when the sync runs outside a jit trace
-with tracing on — one measured ``grad_sync:run`` span plus synthetic
-per-bucket spans (the host cannot see bucket boundaries inside the
-compiled program; same idiom as ``parallel/pipeline``'s tick spans).
+with tracing on — one measured ``grad_sync:run`` span carrying the bucket
+count (the host cannot see bucket boundaries inside the compiled
+program, so it records no per-bucket span).
 """
 
 from __future__ import annotations
@@ -456,33 +456,18 @@ def make_grad_sync(mode: str, mesh: Mesh, local_loss: Callable,
             jax.block_until_ready(grads)
         except BaseException:
             # a raising sync (revoked comm, watchdog timeout) still
-            # closes its span, tagged error — never open-ended, never a
-            # latency sample for the perf cost model
+            # closes its span, tagged error — never open-ended
             trace.record_span(
                 "grad_sync:run", "overlap", t0, time.perf_counter(),
                 args={"mode": mode, "ndev": n, "status": "error"})
             raise
-        t1 = time.perf_counter()
         trace.record_span(
-            "grad_sync:run", "overlap", t0, t1,
+            "grad_sync:run", "overlap", t0, time.perf_counter(),
             args={"mode": mode, "ndev": n,
                   "buckets": _PVARS["grad_bucket_count"]
                   if mode == "bucketed" else None,
                   "total_bytes": _PVARS["grad_bucket_bytes"]
                   if mode == "bucketed" else None})
-        if mode == "bucketed" and _last_plan is not None:
-            # the host cannot see bucket boundaries inside the compiled
-            # program: even subdivision, marked synthetic (the
-            # pipeline-tick idiom)
-            plan, arms = _last_plan
-            per = (t1 - t0) / max(plan.n_buckets, 1)
-            for i, (b, arm) in enumerate(zip(plan.buckets, arms)):
-                trace.record_span(
-                    "grad_sync:bucket", "overlap-buckets",
-                    t0 + i * per, t0 + (i + 1) * per,
-                    args={"bucket": i, "synthetic": True, "arm": arm,
-                          "nbytes": b.nbytes, "ndev": n,
-                          "leaves": len(b.indices)})
         _note_traffic(grads)
         if numerics.enabled:
             _note_numerics(grads)

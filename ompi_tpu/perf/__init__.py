@@ -22,10 +22,13 @@ Sample sources:
    async: a native sample measures dispatch latency unless the caller
    blocks — the bench probes and the staged arm (which blocks on D2H)
    provide the grounded timings; docs cover the caveat.
-2. ``grad_sync:bucket`` overlap spans through the trace span sink
-   (``trace.set_span_sink``) — spans tagged ``status=error`` (a raising
-   collective, e.g. WatchdogTimeoutError) are NEVER ingested: a stall
-   is not a latency sample.
+2. ``note_sample``: an already-measured dispatch from outside the
+   wrapper (the reshard executor's plan steps, the fused decode rings).
+
+No trace span feeds the model: a span's duration is not a dispatch the
+plane measured (the grad-sync bucket split, for one, is invisible to the
+host inside the compiled step), so grad_sync arms are learned only from
+timed dispatches.
 
 Disabled path (the default): ONE module attribute read
 (``perf.enabled``) per instrumented call site — the same bar as
@@ -45,7 +48,6 @@ import time
 from typing import Any, Dict, Optional, Tuple
 
 from ..core import var as _var
-from .. import trace as _trace
 from .goodput import GoodputLedger, account, pipeline_bubble_s  # noqa: F401
 from .model import CostModel, busbw_GBps, size_bucket  # noqa: F401
 from .sentry import Sentry
@@ -198,29 +200,6 @@ def note_sample(coll: str, arm: str, nbytes: int, dur_s: float,
         if plane != "host" and int(pb) > 0:
             model.record(f"{coll}@{plane}", str(arm), int(pb), dur,
                          int(ndev))
-
-
-# ---- sample source 2: the trace span sink ----------------------------
-
-def _ingest_span(name: str, cat: str, t_begin: float, t_end: float,
-                 args: Optional[Dict[str, Any]]) -> None:
-    if not enabled:
-        return
-    if name != "grad_sync:bucket":     # whitelist: everything else is
-        return                         # already counted at dispatch
-    a = args or {}
-    if a.get("status") == "error":     # satellite fix: never ingest a
-        return                         # stall/raise as a latency sample
-    arm, nbytes = a.get("arm"), a.get("nbytes")
-    ndev = int(a.get("ndev") or 0)
-    if not arm or not nbytes or ndev < 2:
-        return
-    dur = max(t_end - t_begin, 0.0)
-    model.record("grad_sync", str(arm), int(nbytes), dur, ndev)
-    sentry.observe_coll("grad_sync", str(arm), int(nbytes), dur, ndev)
-
-
-_trace.set_span_sink(_ingest_span)
 
 
 # ---- learned arm selection (coll/xla decide_mode) --------------------

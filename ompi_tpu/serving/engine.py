@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import trace
 from ..models.transformer import (_rms_norm, decode_attention,
                                   rope_rows)
 from ..parallel.ring import attention_reference
@@ -240,7 +241,7 @@ def _audit_serve_coll(dc, coll: str, arm: str, reason: str,
     if simdcn.us_per_mib() > 0:
         simdcn.charge(int(wire * simdcn.ring_dcn_fraction(dc.mesh,
                                                           dc.axis)))
-    from .. import perf, trace, traffic
+    from .. import perf, traffic
     if perf.enabled:
         # bank under the LOGICAL payload bytes (what decide_mode sees),
         # not the per-arm wire bytes — otherwise native and quant land
@@ -409,6 +410,7 @@ class ServingEngine:
 
     # -- audited collective dispatch ---------------------------------------
 
+    @trace.timed("ompi.engine.decode_ag")
     def _ag(self, x):
         t0 = time.perf_counter()
         arm, reason, chain = _decide_serve_coll(
@@ -424,6 +426,7 @@ class ServingEngine:
             note_dispatch("eager")
         return out
 
+    @trace.timed("ompi.engine.decode_rs")
     def _rs(self, x):
         t0 = time.perf_counter()
         arm, reason, chain = _decide_serve_coll(
@@ -445,22 +448,23 @@ class ServingEngine:
                   attend: Callable) -> Any:
         cfg = self.cfg
         for i, lw in enumerate(self._layers):
-            q, k, v = _j_qkv(x, lw["attn_norm"], lw["wqkv"], pos_dev,
-                             head_dim=cfg.head_dim,
-                             base=float(cfg.rope_base))
-            self.cache.k[i], self.cache.v[i] = _j_page_write(
-                self.cache.k[i], self.cache.v[i], k, v, page_idx,
-                offset)
-            att = attend(i, q, k, v)
-            o = _j_o_proj(self._ag(att), lw["wo"])
-            if self.moe:
-                x = _j_residual(self._ag(o), x)
-                x = self._moe_mlp(x, lw)
-            else:
-                x, z = _j_mlp_in(self._ag(o), x, lw["mlp_norm"],
-                                 lw["w_gate"], lw["w_up"])
-                d = _j_mlp_down(self._ag(z), lw["w_down"])
-                x = _j_residual(self._ag(d), x)
+            with trace.region("ompi.engine.layer"):
+                q, k, v = _j_qkv(x, lw["attn_norm"], lw["wqkv"], pos_dev,
+                                 head_dim=cfg.head_dim,
+                                 base=float(cfg.rope_base))
+                self.cache.k[i], self.cache.v[i] = _j_page_write(
+                    self.cache.k[i], self.cache.v[i], k, v, page_idx,
+                    offset)
+                att = attend(i, q, k, v)
+                o = _j_o_proj(self._ag(att), lw["wo"])
+                if self.moe:
+                    x = _j_residual(self._ag(o), x)
+                    x = self._moe_mlp(x, lw)
+                else:
+                    x, z = _j_mlp_in(self._ag(o), x, lw["mlp_norm"],
+                                     lw["w_gate"], lw["w_up"])
+                    d = _j_mlp_down(self._ag(z), lw["w_down"])
+                    x = _j_residual(self._ag(d), x)
         return x
 
     def _moe_mlp(self, x, lw):
@@ -487,15 +491,17 @@ class ServingEngine:
 
     def _audit_collmm(self, site: str, payload: int, wire: int,
                       arm: str, reason: str, chain: List[str],
-                      dur_s: float, rows: int) -> None:
+                      rows: int) -> None:
         """One decision-audit record per fused ring — the decode_collmm
         counterpart of ``_audit_serve_coll``.  The ring is an n−1-hop
         ppermute rotation, so the wire figure is exact (no per-arm
         model): it is charged to the ring edges via ``note_ring``
         (``decode_collmm`` is not in traffic's coll→pattern table, and
         ``note_coll`` would file it unattributed) and mirrored into
-        ``coll_wire_bytes`` so conservation's two halves still meet."""
-        from .. import perf, trace, traffic
+        ``coll_wire_bytes`` so conservation's two halves still meet.
+        No perf sample: the rings run inside one program, so no ring's
+        own duration is measured."""
+        from .. import traffic
         dc = self.dc
         spc = dc.spc
         if spc is not None:
@@ -505,9 +511,6 @@ class ServingEngine:
         if simdcn.us_per_mib() > 0:
             simdcn.charge(int(wire * simdcn.ring_dcn_fraction(dc.mesh,
                                                               dc.axis)))
-        if perf.enabled:
-            perf.note_sample("decode_collmm", arm, int(payload), dur_s,
-                             dc.n)
         if traffic.enabled:
             traffic.note_ring(dc.mesh, dc.axis, int(wire),
                               "decode_collmm", "fwd")
@@ -558,21 +561,17 @@ class ServingEngine:
             self._embed,
             jnp.asarray(np.where(positions >= 0, tokens,
                                  0).astype(np.int32)))))
-        t0 = time.perf_counter()
         lg_can, new_k, new_v = self._fused(
             x, jnp.asarray(bt),
             jnp.asarray(positions.astype(np.int32)),
             jnp.asarray(page_idx), jnp.asarray(offset),
             tuple(self._fused_layers), jnp.asarray(self._final_norm),
             self._embed_lg, tuple(self.cache.k), tuple(self.cache.v))
-        jax.block_until_ready(lg_can)
-        dur = time.perf_counter() - t0
         self.cache.k[:] = list(new_k)
         self.cache.v[:] = list(new_v)
-        share = dur / max(len(decided), 1)
         for site, payload, wire, arm, reason, chain in decided:
             self._audit_collmm(site, payload, wire, arm, reason, chain,
-                               share, rows)
+                               rows)
         logits, nxt = _j_fused_logits_argmax(self._ag(lg_can))
         return logits, nxt
 
@@ -598,7 +597,6 @@ class ServingEngine:
         so compilations stay bounded; padded positions write to the
         scratch page and never enter the causal window.  ``rid`` tags
         the emitted span with the owning request (CL008)."""
-        from .. import trace
         prompt = np.asarray(prompt, np.int32)
         s = int(prompt.shape[0])
         spad = self._bucket(s)
@@ -608,24 +606,22 @@ class ServingEngine:
         live_pos = np.where(positions < s, positions, -1)
         page_idx, offset = self.cache.write_indices(
             np.full(spad, slot), live_pos)
-        t0 = time.perf_counter()
-        try:
-            x = _j_regroup(self._ag(_j_embed(self._embed,
-                                             jnp.asarray(tok))))
-            x = self._backbone(
-                x, jnp.asarray(positions.astype(np.int32)),
-                jnp.asarray(page_idx), jnp.asarray(offset),
-                lambda i, q, k, v: _j_prefill_attn(q, k, v))
-            logits, nxt = self._logits(_j_last_pos(x, s=s), b=1)
-            jax.block_until_ready(nxt)
-        finally:
-            if trace.enabled:
-                trace.record_span("serve:prefill", "serve", t0,
-                                  time.perf_counter(),
-                                  args={"slot": slot, "prompt_len": s,
-                                        "rid": rid})
+        with trace.region("ompi.engine.prefill", "serve:prefill", "serve",
+                          args={"slot": slot, "prompt_len": s, "rid": rid}
+                          if trace.enabled else None):
+            with trace.region("ompi.engine.prefill.dispatch"):
+                x = _j_regroup(self._ag(_j_embed(self._embed,
+                                                 jnp.asarray(tok))))
+                x = self._backbone(
+                    x, jnp.asarray(positions.astype(np.int32)),
+                    jnp.asarray(page_idx), jnp.asarray(offset),
+                    lambda i, q, k, v: _j_prefill_attn(q, k, v))
+                logits, nxt = self._logits(_j_last_pos(x, s=s), b=1)
+            with trace.region("ompi.engine.prefill.wait"):
+                jax.block_until_ready(nxt)
+                first = int(np.asarray(jax.device_get(nxt))[0, 0])
         self.cache.seq_lens[slot] = s
-        return int(np.asarray(jax.device_get(nxt))[0, 0]), logits
+        return first, logits
 
     def decode_step(self, tokens: np.ndarray, positions: np.ndarray):
         """One continuous-batching decode step over the FULL device
@@ -634,44 +630,39 @@ class ServingEngine:
         garbage on the scratch page — the batch shape never changes, so
         one executable serves every occupancy).  Returns (next greedy
         token per slot (max_seqs,), logits (tp, max_seqs, V))."""
-        from .. import trace
         b = self.max_seqs
         tokens = np.asarray(tokens, np.int32)
         positions = np.asarray(positions, np.int64)
         page_idx, offset = self.cache.write_indices(np.arange(b),
                                                     positions)
-        t0 = time.perf_counter()
-        try:
-            if self.fused:
-                logits, nxt = self._decode_step_fused(
-                    tokens, positions, page_idx, offset,
-                    self.cache.block_tables)
+        with trace.region("ompi.engine.decode", "serve:decode_step", "serve",
+                          args={"active": int((positions >= 0).sum()),
+                                "slots": b,
+                                "path": "fused" if self.fused else "eager"}
+                          if trace.enabled else None):
+            with trace.region("ompi.engine.decode.dispatch"):
+                if self.fused:
+                    logits, nxt = self._decode_step_fused(
+                        tokens, positions, page_idx, offset,
+                        self.cache.block_tables)
+                else:
+                    bt = jnp.asarray(self.cache.block_tables)
+                    pos_dev = jnp.asarray(positions.astype(np.int32))
+                    x = _j_regroup(self._ag(_j_embed(
+                        self._embed,
+                        jnp.asarray(np.where(positions >= 0, tokens,
+                                             0).astype(np.int32)))))
+                    x = self._backbone(
+                        x, pos_dev, jnp.asarray(page_idx),
+                        jnp.asarray(offset),
+                        lambda i, q, k, v: _j_paged_attn(
+                            q, self.cache.k[i], self.cache.v[i], bt,
+                            pos_dev))
+                    logits, nxt = self._logits(x, b=b)
+            with trace.region("ompi.engine.decode.wait"):
                 jax.block_until_ready(nxt)
-            else:
-                bt = jnp.asarray(self.cache.block_tables)
-                pos_dev = jnp.asarray(positions.astype(np.int32))
-                x = _j_regroup(self._ag(_j_embed(
-                    self._embed,
-                    jnp.asarray(np.where(positions >= 0, tokens,
-                                         0).astype(np.int32)))))
-                x = self._backbone(
-                    x, pos_dev, jnp.asarray(page_idx),
-                    jnp.asarray(offset),
-                    lambda i, q, k, v: _j_paged_attn(
-                        q, self.cache.k[i], self.cache.v[i], bt,
-                        pos_dev))
-                logits, nxt = self._logits(x, b=b)
-                jax.block_until_ready(nxt)
-        finally:
-            if trace.enabled:
-                # comm-lint: disable=CL008 batch-scoped decode span covers every live rid at once
-                trace.record_span(
-                    "serve:decode_step", "serve", t0,
-                    time.perf_counter(),
-                    args={"active": int((positions >= 0).sum()),
-                          "slots": b, "path": ("fused" if self.fused
-                                               else "eager")})
-        return np.asarray(jax.device_get(nxt))[0], logits
+                out = np.asarray(jax.device_get(nxt))[0]
+        return out, logits
 
     def decode_window(self, tokens: np.ndarray,
                       positions: np.ndarray):
@@ -694,7 +685,6 @@ class ServingEngine:
         window's row count) — and in both, window cost ≈ one step's
         dispatch cost, which is exactly why speculation wins on a
         dispatch-bound fabric."""
-        from .. import trace
         b = self.max_seqs
         tokens = np.asarray(tokens, np.int32)
         positions = np.asarray(positions, np.int64)
@@ -705,13 +695,15 @@ class ServingEngine:
         bt = np.repeat(self.cache.block_tables, k, axis=0)
         flat_tok = np.where(positions >= 0, tokens, 0).reshape(-1)
         flat_pos = positions.reshape(-1)
-        t0 = time.perf_counter()
-        try:
+        with trace.region("ompi.engine.decode_window", "serve:decode_window",
+                          "serve",
+                          args={"active": int((positions[:, 0] >= 0).sum()),
+                                "slots": b, "k": k}
+                          if trace.enabled else None):
             if self.fused:
                 logits, nxt = self._decode_step_fused(
                     flat_tok, flat_pos, page_idx.reshape(-1),
                     offset.reshape(-1), bt)
-                jax.block_until_ready(nxt)
             else:
                 pos_dev = jnp.asarray(flat_pos.astype(np.int32))
                 btj = jnp.asarray(bt)
@@ -725,15 +717,7 @@ class ServingEngine:
                         q, self.cache.k[i], self.cache.v[i], btj,
                         pos_dev))
                 logits, nxt = self._logits(x, b=b * k)
-                jax.block_until_ready(nxt)
-        finally:
-            if trace.enabled:
-                # comm-lint: disable=CL008 batch-scoped verify window covers every live rid at once
-                trace.record_span(
-                    "serve:decode_window", "serve", t0,
-                    time.perf_counter(),
-                    args={"active": int((positions[:, 0] >= 0).sum()),
-                          "slots": b, "k": k})
+            jax.block_until_ready(nxt)
         return (np.asarray(jax.device_get(nxt))[0].reshape(b, k),
                 logits)
 

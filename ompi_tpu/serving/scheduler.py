@@ -30,7 +30,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .. import serving
+from .. import serving, trace
 from . import requests as _requests
 
 
@@ -109,6 +109,7 @@ class ContinuousBatchingScheduler:
 
     # -- lifecycle ---------------------------------------------------------
 
+    @trace.timed("ompi.serve.admit")
     def _admit_one(self, req: Request) -> None:
         cache = self.engine.cache
         slot = cache.admit(len(req.prompt), req.max_new)
@@ -199,6 +200,7 @@ class ContinuousBatchingScheduler:
                                    "decode steps without draining")
         return self.summary()
 
+    @trace.timed("ompi.serve.step")
     def _step(self) -> None:
         cache = self.engine.cache
         b = self.engine.max_seqs

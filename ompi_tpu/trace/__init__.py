@@ -18,15 +18,23 @@ One event schema shared by every instrumented layer:
   * ``parallel/overlap``      — one DECISION instant per grad-sync
     bucket (arm native | quant, bucket index/bytes/leaf count;
     ``explain_last("grad_sync")``) and per collective-matmul call site
-    (``explain_last("collmm")``, arm native | bidir); plus a measured
-    ``grad_sync:run`` span with synthetic per-bucket spans when the
-    sync executes outside an enclosing jit trace.
+    (``explain_last("collmm")``, arm native | bidir); plus one measured
+    ``grad_sync:run`` span (bucket count in its args) when the sync
+    executes outside an enclosing jit trace.
 
 Cost contract: every instrumented call site is gated on the module-level
 ``trace.enabled`` flag — ONE attribute read on the disabled path, no
 argument construction, no locking.  Recording goes into a fixed-capacity
 per-rank ring buffer; overflow overwrites the oldest event and counts
 ``trace_dropped_events`` (surfaced as an MPI_T pvar via ``spc``).
+
+Timed regions: ``with trace.region("ompi.<layer>.<step>"):`` marks a
+host-side step of the hot path (collective dispatch, the serving loop).
+While a ``jax.profiler`` session records, the region is a
+``TraceAnnotation`` in the profiler's own host plane, beside the device
+ops, and adds count, total and self time to the table that
+``regions()`` returns; with the ring on it records a ring span; with
+neither it is one shared no-op (docs/observability.md).
 
 Exporters: ``save_chrome(path)`` writes Chrome-trace JSON (object form,
 perfetto-loadable; pid = rank, tid = one lane per category so nested
@@ -43,11 +51,13 @@ straggler / bubble / decision-drift reports over it, and
 
 from __future__ import annotations
 
+import functools
 import json
 import math
+import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from ..core import var as _var
 
@@ -143,11 +153,14 @@ _var.watch("trace_buffer_events", _set_capacity)
 
 
 def clear() -> None:
-    """Drop all recorded events, decisions and the dropped counter."""
+    """Drop all recorded events, decisions, region totals and the
+    dropped counter."""
     global _dropped
     with _lock:
         _rings.clear()
         _last.clear()
+        for t in _threads:
+            t.table.clear()
         _dropped = 0
 
 
@@ -185,39 +198,20 @@ def flow(name: str, cat: str, fid: int, ph: str, rank: int = 0,
            "rank": int(rank), "args": args or {}})
 
 
-# One downstream consumer may register for span completions (the perf
-# cost model ingests grad_sync bucket spans this way).  A sink failure
-# must never take down the traced operation itself.
-_span_sink = None
-
-
-def set_span_sink(fn) -> None:
-    """Register ``fn(name, cat, t_begin, t_end, args)`` to observe every
-    recorded span (None unregisters)."""
-    global _span_sink
-    _span_sink = fn
-
-
 def record_span(name: str, cat: str, t_begin: float, t_end: float,
                 rank: int = 0, args: Optional[dict] = None) -> None:
     """Record an already-timed complete span (perf_counter() endpoints)."""
     _emit({"name": name, "cat": cat, "ph": "X", "t": t_begin,
            "dur": max(0.0, t_end - t_begin), "rank": int(rank),
            "args": args or {}})
-    if _span_sink is not None:
-        try:
-            _span_sink(name, cat, t_begin, t_end, args)
-        except Exception:
-            pass
 
 
 class span:
     """Context manager recording one complete span on exit.  Construct it
     only behind a ``trace.enabled`` check — building ``args`` is the cost.
-    A body that raises still closes the span, tagged ``status=error`` —
-    downstream consumers (the perf cost model) must never mistake a
-    stalled-then-raised collective (e.g. WatchdogTimeoutError) for a
-    latency sample."""
+    A body that raises still closes the span, tagged ``status=error``, so
+    a reader never mistakes a stalled-then-raised collective (e.g.
+    WatchdogTimeoutError) for a latency sample."""
 
     __slots__ = ("name", "cat", "rank", "args", "_begin")
 
@@ -237,6 +231,193 @@ class span:
         record_span(self.name, self.cat, self._begin, time.perf_counter(),
                     self.rank, args)
         return False
+
+
+# -- timed regions on the profiler's clock -----------------------------------
+
+COMPILE_REGION = "ompi.compile"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _Thread:
+    """One thread's open regions and its totals (name -> [count, total_s,
+    self_s]): a region never takes a lock."""
+
+    __slots__ = ("stack", "table")
+
+    def __init__(self) -> None:
+        self.stack: List["_Region"] = []
+        self.table: Dict[str, List[float]] = {}
+
+
+_threads: List[_Thread] = []               # every thread's, for regions()
+_region_tls = threading.local()            # .state: this thread's _Thread
+_Annotation = None                         # jax.profiler.TraceAnnotation
+
+
+def _thread() -> _Thread:
+    st = getattr(_region_tls, "state", None)
+    if st is None:
+        st = _region_tls.state = _Thread()
+        with _lock:
+            _threads.append(st)
+    return st
+
+
+def _add(table: Dict[str, List[float]], name: str, total: float,
+         own: float) -> None:
+    row = table.get(name)
+    if row is None:
+        row = table[name] = [0, 0.0, 0.0]
+    row[0] += 1
+    row[1] += total
+    row[2] += own
+
+
+def _profiler_unbound() -> bool:
+    """No profiler session can record before jax is imported; once it is,
+    bind to jax's own flag and answer from it."""
+    if "jax" not in sys.modules:
+        return False
+    _bind_profiler()
+    return _recording()
+
+
+# Whether a jax.profiler session records: TraceAnnotation.is_enabled
+# once jax is imported (a C++ flag read).
+_recording = _profiler_unbound
+
+
+def _on_compile(event: str, duration: float, **kw: Any) -> None:
+    # jax times every backend compile, a persistent-cache load included,
+    # under this one event
+    if event == _COMPILE_EVENT and _recording():
+        _add(_thread().table, COMPILE_REGION, duration, duration)
+
+
+def _bind_profiler() -> None:
+    global _Annotation, _recording
+    with _lock:
+        if _Annotation is not None:
+            return
+        import jax.monitoring
+        import jax.profiler
+        _Annotation = jax.profiler.TraceAnnotation
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _recording = _Annotation.is_enabled
+
+
+class _NoRegion:
+    """The region of the untraced path: shared, allocation-free."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoRegion":
+        return self
+
+    def __exit__(self, et: Any, ev: Any, tb: Any) -> bool:
+        return False
+
+
+_NO_REGION = _NoRegion()
+
+
+class _Region:
+    __slots__ = ("name", "profiled", "ring", "cat", "rank", "args", "_ann",
+                 "_thr", "_t0", "_child")
+
+    def __init__(self, name: str, profiled: bool, ring: Optional[str],
+                 cat: str, rank: int, args: Optional[dict]) -> None:
+        self.name, self.profiled, self.ring = name, profiled, ring
+        self.cat, self.rank, self.args = cat, rank, args
+
+    def __enter__(self) -> "_Region":
+        # a region's own annotation cost lies inside its interval, so a
+        # parent's self time does not carry its children's instrumentation
+        self._t0 = time.perf_counter()
+        if self.profiled:
+            self._ann = _Annotation(self.name)
+            self._ann.__enter__()
+            self._child = 0.0
+            self._thr = thr = _thread()
+            thr.stack.append(self)
+        return self
+
+    def __exit__(self, et: Any, ev: Any, tb: Any) -> bool:
+        if self.profiled:
+            self._ann.__exit__(et, ev, tb)
+            stack = self._thr.stack
+            stack.pop()
+            dur = time.perf_counter() - self._t0
+            if stack:
+                stack[-1]._child += dur
+            _add(self._thr.table, self.name, dur, dur - self._child)
+        if self.ring is not None:
+            args = self.args
+            if et is not None:
+                args = dict(args or {})
+                args["status"] = "error"
+            record_span(self.ring, self.cat, self._t0, time.perf_counter(),
+                        self.rank, args)
+        return False
+
+
+def region(name: str, ring: Optional[str] = None, cat: Optional[str] = None,
+           rank: int = 0, args: Optional[dict] = None):
+    """Context manager timing one host-side step ``name``
+    (``ompi.<layer>.<step>``), in one of three states:
+
+    * a ``jax.profiler`` session records: a ``TraceAnnotation(name)`` in
+      the profiler's host plane, and count / total / self time (total
+      less the child regions it encloses, per thread) added to the
+      :func:`regions` table, which therefore covers exactly the traced
+      window;
+    * ``trace.enabled``: one ring span, under ``ring`` (default
+      ``name``) in category ``cat`` (default ``name``: one Chrome lane
+      per region, so nested regions never share a lane), tagged
+      ``status=error`` when the body raises;
+    * neither: the shared no-op, with no allocation and no clock read.
+
+    Build ``args`` (ring span args) only when ``trace.enabled``."""
+    profiled = _recording()
+    if not (profiled or enabled):
+        return _NO_REGION
+    return _Region(name, profiled, (ring or name) if enabled else None,
+                   cat or name, rank, args)
+
+
+def timed(name: str) -> Callable:
+    """Decorator: every call of the function is ``region(name)``."""
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def timed_call(*a: Any, **kw: Any) -> Any:
+            with region(name):
+                return fn(*a, **kw)
+        return timed_call
+    return wrap
+
+
+def regions() -> Dict[str, Dict[str, float]]:
+    """``{name: {"count", "total_s", "self_s"}}`` of every region closed
+    while a profiler session recorded, summed over threads, plus
+    ``ompi.compile``: the backend compiles and persistent-cache loads in
+    that time.  ``clear()`` empties it."""
+    out: Dict[str, Dict[str, float]] = {}
+    with _lock:
+        tables = [dict(t.table) for t in _threads]
+    for table in tables:
+        for n, (c, tot, own) in table.items():
+            row = out.setdefault(n, {"count": 0, "total_s": 0.0,
+                                     "self_s": 0.0})
+            row["count"] += int(c)
+            row["total_s"] += tot
+            row["self_s"] += own
+    return out
+
+
+if "jax" in sys.modules:
+    # bind now, so that compiles are counted before the first region runs
+    _bind_profiler()
 
 
 def decision(op: str, arm: str, reason: str, nbytes: int, rank: int = 0,
@@ -320,6 +501,10 @@ def _jsonable(d: Optional[dict]) -> dict:
     return out
 
 
+def _floor_us(seconds: float) -> int:
+    return int(round(seconds * 1e9)) // 1000
+
+
 def chrome_doc(evs: List[dict], t0: float) -> dict:
     """Build a Chrome-trace document (object form with a ``traceEvents``
     list — loadable in perfetto / chrome://tracing) from event dicts.
@@ -327,7 +512,9 @@ def chrome_doc(evs: List[dict], t0: float) -> dict:
     pid = rank; tid = one lane per event category, so spans from
     different layers (a compile span inside a quant span) never overlap
     within a (pid, tid) lane.  Timestamps are µs since ``t0``,
-    floor-rounded so span ends never cross the next span's start.
+    floor-rounded so span ends never cross the next span's start (from
+    the nearest ns, so one instant reached by two float paths, an end
+    and the next start, rounds alike).
     Shared by :func:`save_chrome` (this process's rings, trace epoch
     origin) and ``trace.merge`` (offset-aligned fleet timeline, earliest
     event origin)."""
@@ -339,14 +526,14 @@ def chrome_doc(evs: List[dict], t0: float) -> dict:
         if tid is None:
             tid = tids[e["cat"]] = len(tids) + 1
         pids.add(e["rank"])
-        ts = int((e["t"] - t0) * 1e6)
+        ts = _floor_us(e["t"] - t0)
         row = {"name": e["name"], "cat": e["cat"], "ph": e["ph"],
                "ts": ts, "pid": e["rank"], "tid": tid,
                "args": _jsonable(e["args"])}
         if e["ph"] == "X":
             # floor both endpoints: ts+dur <= the true end, so ordered
             # spans stay non-overlapping after µs rounding
-            row["dur"] = max(0, int((e["t"] + e["dur"] - t0) * 1e6) - ts)
+            row["dur"] = max(0, _floor_us(e["t"] + e["dur"] - t0) - ts)
         elif e["ph"] == "i":
             row["s"] = "t"
         elif e["ph"] in _FLOW_PHASES:

@@ -194,8 +194,8 @@ def latency_histograms(tl: FleetTimeline) -> Dict[str, Any]:
         nbytes = e["args"].get("wire_bytes") or e["args"].get("nbytes")
         ndev = e["args"].get("ndev") or len(tl.ranks) or 1
         if nbytes and e["dur"] > 0:
-            # "quant:allreduce" keys on allreduce; "grad_sync:bucket"
-            # on grad_sync — first known op name anywhere in the span name
+            # "quant:allreduce" keys on allreduce — the first known op
+            # name anywhere in the span name
             parts = e["name"].split(":")
             fn = next((_BUSBW_FACTOR[p] for p in reversed(parts)
                        if p in _BUSBW_FACTOR), lambda r: 1.0)

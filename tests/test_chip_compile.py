@@ -12,6 +12,7 @@ its tests exist gives pytest-xdist workers different collections.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +64,13 @@ def test_flash_mha_fwd_bwd_flagship_shape(one_chip):
         *args).compile().as_text()
     # forward + dq + dk/dv kernels, all compiled to Mosaic
     assert hlo.count("tpu_custom_call") >= 3
+    # each under its own name, which the profiler's op events carry
+    # (a transform may wrap it: transpose_jvp_ompi_flash_bwd_dq__)
+    ops = re.findall(r"%(\S+) = .*tpu_custom_call", hlo)
+    stable = ("ompi_flash_partials", "ompi_flash_bwd_dkv",
+              "ompi_flash_bwd_dq")
+    assert all(any(k in op for k in stable) for op in ops), ops
+    assert all(any(k in op for op in ops) for k in stable), ops
 
 
 def test_flash_partials_ring_shard(one_chip):
@@ -73,6 +81,7 @@ def test_flash_partials_ring_shard(one_chip):
         q, k, v, causal=True, q_offset=512, kv_offset=0, interpret=False))
     hlo = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    assert "%ompi_flash_partials" in hlo
 
 
 def test_device_comm_collectives_four_chips(topo):

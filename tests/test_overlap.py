@@ -217,11 +217,15 @@ class TestGradSyncObservability:
         finally:
             trace.disable()
         runs = [e for e in evs if e["name"] == "grad_sync:run"]
-        buckets = [e for e in evs if e["name"] == "grad_sync:bucket"]
         assert len(runs) == 1
         assert runs[0]["args"]["mode"] == "bucketed"
-        assert len(buckets) == runs[0]["args"]["buckets"]
-        assert all(b["args"]["synthetic"] for b in buckets)
+        assert runs[0]["dur"] > 0 and "status" not in runs[0]["args"]
+        # the bucket count rides on the one measured span; no span is
+        # made up per bucket (the host cannot time one inside the step)
+        plan = overlap.bucket_plan(
+            jax.tree.leaves(init_params(jax.random.key(0), cfg)), 4096)
+        assert runs[0]["args"]["buckets"] == plan.n_buckets >= 2
+        assert not [e for e in evs if e["name"] == "grad_sync:bucket"]
 
     def test_explain_last_and_pvars(self):
         mesh = make_mesh({"dp": 8})
