@@ -1145,10 +1145,20 @@ class DeviceComm:
         result as ``pack_ragged_blocks`` + :meth:`alltoallv` — but the
         (R, R, cap) padded block tensor NEVER materializes anywhere.
         The capacity dimension is processed in ``slice_cap``-sized slices
-        inside one ``lax.scan``: each step gathers the slice of every
-        destination block from the dense row (device-side, from cumsum
-        offsets), exchanges it with one dense ``all_to_all``, and
-        scatters it into its final position in the output. Peak extra HBM
+        inside one ``lax.scan``. Each (source, destination) segment is one
+        contiguous run in the sender's row and one in the receiver's, so a
+        step moves it with per-peer contiguous slices, never per-element
+        index work: the sender takes ``S = slice_cap`` rows of its row at
+        ``soff + min(base, C)`` for each destination (``dynamic_slice``,
+        offsets from device-cached cumsum maps), one dense ``all_to_all``
+        exchanges them, and the receiver writes each source's slice at
+        ``roff + min(base, C)`` as a read-modify-write that keeps the
+        slice's invalid tail from clobbering the next source's run. The
+        send row is padded with ``S`` zero rows once, before the scan:
+        ``dynamic_slice`` clamps its start so the window fits, and a
+        window near the row's end would otherwise shift and send the
+        wrong elements. The output carry is ``out_cap + S`` long for the
+        same reason. Peak extra HBM
         per device is O(R·slice_cap·r) instead of O(R·cap·r) — at the
         bench's 16 MB/rank ragged shape that is the difference between a
         256 MiB resident padding blowup (the round-2→5 sweep truncation)
@@ -1162,7 +1172,6 @@ class DeviceComm:
         C = np.asarray(counts, dtype=np.int64)
         R = x.shape[0]
         r = R // self.n
-        L = x.shape[1]
         plan = self.a2av_plan(x.shape, C, slice_cap)
         slice_cap = plan["slice_cap"]
         k = plan["scan_steps"]
@@ -1197,33 +1206,31 @@ class DeviceComm:
             def inner(xs, soff, crow, rofft, ccolt):
                 # xs (r, L, *e); soff/crow: send offsets/counts for the
                 # LOCAL source rows; rofft/ccolt: recv offsets/counts for
-                # the LOCAL destination rows (transposed views)
+                # the LOCAL destination rows (transposed views). Every
+                # slice below has a static row and a scalar start: no
+                # per-element gather or scatter (a vmapped dynamic_slice
+                # would batch its start and lower to a gather).
                 rr = xs.shape[0]
-                p = jnp.arange(S, dtype=jnp.int32)
+                zeros_e = (0,) * len(e_shape)
+                p = jnp.arange(S, dtype=jnp.int32).reshape(
+                    (S,) + (1,) * len(e_shape))
+                win = (1, S) + e_shape
+                # S zero rows past the end: a window that starts near the
+                # row's end would otherwise be clamped back and shifted
+                xp = jnp.pad(xs, ((0, 0), (0, S)) + ((0, 0),) * len(e_shape))
 
-                def one_row_gather(row, off, cnt, base):
-                    # (L, *e), (R,), (R,) → (R, S, *e) slice of each block
-                    src = off[:, None] + base + p[None, :]
-                    valid = (base + p)[None, :] < cnt[:, None]
-                    g = jnp.take(row, jnp.clip(src, 0, L - 1).reshape(-1),
-                                 axis=0).reshape((R, S) + e_shape)
-                    m = valid.reshape((R, S) + (1,) * len(e_shape))
-                    return jnp.where(m, g, jnp.zeros_like(g))
-
-                def one_row_scatter(out, vals, off, cnt, base):
-                    # out (out_cap+S, *e); vals (R, S, *e): place block
-                    # slice from source i at roff + base + p
-                    pos = off[:, None] + base + p[None, :]
-                    valid = (base + p)[None, :] < cnt[:, None]
-                    pos = jnp.where(valid, pos, out_cap)   # trash slot
-                    return out.at[pos.reshape(-1)].set(
-                        vals.reshape((R * S,) + e_shape))
+                def window(buf, a, st):
+                    return lax.dynamic_slice(buf, (a, st) + zeros_e, win)[0]
 
                 def body(out, s):
                     base = s * S
-                    g = jax.vmap(one_row_gather,
-                                 in_axes=(0, 0, 0, None))(
-                        xs, soff, crow, base)              # (rr, R, S, *e)
+                    # send side: block j's next S elements, one contiguous
+                    # run of the row from soff + min(base, C); positions
+                    # past C - base are never kept by the receiver
+                    sst = soff + jnp.minimum(base, crow)      # (rr, R)
+                    g = jnp.stack([jnp.stack([
+                        window(xp, a, sst[a, j]) for j in range(R)])
+                        for a in range(rr)])                  # (rr, R, S, *e)
                     if r == 1:
                         mixed = lax.all_to_all(g, self.axis, split_axis=1,
                                                concat_axis=1, tiled=True)
@@ -1231,9 +1238,19 @@ class DeviceComm:
                         mixed = lax.all_to_all(g, self.axis, split_axis=1,
                                                concat_axis=0, tiled=True)
                         mixed = jnp.swapaxes(mixed, 0, 1)  # (rr, R, S, *e)
-                    out = jax.vmap(one_row_scatter,
-                                   in_axes=(0, 0, 0, 0, None))(
-                        out, mixed, rofft, ccolt, base)
+                    # receive side: source i's valid prefix lands at
+                    # roff + min(base, C); its invalid tail keeps what the
+                    # window holds (the next source's run), so sources go
+                    # in order as read-modify-writes
+                    rst = rofft + jnp.minimum(base, ccolt)    # (rr, R)
+                    nv = jnp.clip(ccolt - base, 0, S)
+                    for a in range(rr):
+                        for i in range(R):
+                            st = rst[a, i]
+                            kept = jnp.where(p < nv[a, i], mixed[a, i],
+                                             window(out, a, st))
+                            out = lax.dynamic_update_slice(
+                                out, kept[None], (a, st) + zeros_e)
                     return out, None
 
                 out0 = jnp.zeros((rr, out_cap + S) + e_shape, xs.dtype)

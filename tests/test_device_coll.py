@@ -928,6 +928,69 @@ def test_alltoallv_from_rows_cache_not_stale_across_caps(dc):
     np.testing.assert_allclose(host, want, rtol=1e-6)
 
 
+def _edge_counts(name: str) -> np.ndarray:
+    rng = np.random.default_rng(17)
+    if name == "osu":            # the benchmark's uneven circulant split
+        from benchmark.references.osu import a2av_counts
+        return a2av_counts(N, 8 * 37)
+    if name == "zeros":          # rank 2 sends nothing, rank 5 gets nothing
+        C = rng.integers(0, 6, size=(N, N))
+        C[2, :] = 0
+        C[:, 5] = 0
+        return C
+    if name == "fills_L":        # every row ends at L, on a 1-element run:
+        C = rng.integers(2, 7, size=(N, N))   # a window there would clamp
+        C[:, -1] = 1
+        C[:, 0] += C.sum(axis=1).max() - C.sum(axis=1)
+        return C
+    assert name == "multiple"    # every count a multiple of 3
+    return 3 * rng.integers(0, 5, size=(N, N))
+
+
+@pytest.mark.parametrize("counts,slice_cap,elem", [
+    ("osu", None, ()), ("osu", 1, ()), ("osu", 3, ()), ("osu", 3, (2,)),
+    ("zeros", None, ()), ("zeros", 3, (2,)),
+    ("fills_L", None, ()), ("fills_L", 3, ()), ("fills_L", 1, (2,)),
+    ("multiple", 3, ()), ("multiple", None, (2,)),
+])
+def test_alltoallv_from_rows_edges_exact(dc, counts, slice_cap, elem):
+    """The per-peer contiguous slices give EXACTLY the host oracle's
+    compact rows, zeros past each row's total, at the edges a slice can
+    get wrong: empty senders and receivers, a segment ending at the row's
+    end, counts on and off a slice boundary, trailing elem dims, and (on
+    the 4- and 1-device meshes) several rank rows per device."""
+    C = _edge_counts(counts)
+    L = int(C.sum(axis=1).max())
+    rows = (np.arange(N * L * int(np.prod(elem)), dtype=np.float32)
+            .reshape((N, L) + elem) + 1.0)
+    x = jax.device_put(jnp.asarray(rows), dc.sharding())
+    got, got_counts = dc.alltoallv_from_rows(x, C, slice_cap=slice_cap)
+    host = np.asarray(jax.device_get(got))
+    assert host.shape[1] == dc.a2av_plan(rows.shape, C, slice_cap)["out_cap"]
+    assert got_counts == [int(t) for t in C.sum(axis=0)]
+    np.testing.assert_array_equal(
+        host, DeviceComm.compact_from_rows(rows, C, host.shape[1]))
+
+
+@pytest.mark.parametrize("elem", [(), (3,)], ids=["1d", "elem_dim"])
+def test_alltoallv_from_rows_program_has_no_gather_or_scatter(dc, elem):
+    """The segments move as contiguous slices: the compiled program holds
+    no per-element gather or scatter (a scatter with non-unique indices
+    lowers serially, through a sort, on the TPU)."""
+    import re
+    from benchmark.references.osu import a2av_counts
+    C = a2av_counts(N, 8 * 64)
+    shape = (N, int(C.sum(axis=1).max())) + elem
+    x = jax.device_put(jnp.zeros(shape, jnp.float32), dc.sharding())
+    dc.alltoallv_from_rows(x, C)
+    [fn] = [f for k, f in dc._cache.items()
+            if k[0] == "alltoallv_from_rows" and k[1] == shape]
+    maps = dc._idx_cached(("a2av_rows", C.tobytes()), None)
+    hlo = fn.lower(x, *maps).compile().as_text()
+    assert not re.findall(r"(?<![\w-])(?:gather|scatter)\(", hlo)
+    assert "dynamic-update-slice" in hlo
+
+
 class TestCommLevelDenseRowsAlltoallv:
     """MPI's ACTUAL alltoallv buffer layout (dense rows + counts, default
     displacements) through comm.coll — routed to the sliced dense-rows
