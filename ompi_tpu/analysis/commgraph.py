@@ -21,6 +21,10 @@ Three consumers:
 * ``from_reshard_plan(plan)`` — the reshard plan compiler's step list
   is already a static collective program; lift it into the same
   representation so bijection/axis checks and wire prediction apply.
+* ``from_compiled(compiled, mesh)`` — the collectives of a compiled,
+  partitioned program, read from its optimized HLO: op, mesh axes
+  (from the replica groups or source-target pairs), dtype and per-shard
+  payload of every instruction, each executed once per call.
 * ``verify(fn, args, mesh)`` — checks + static wire prediction + a
   live run under the traffic plane, comparing the static figure with
   the runtime per-coll attribution **byte-for-byte** (same integer
@@ -31,16 +35,19 @@ Three consumers:
 What the extractor can and cannot see: explicit collectives (shard_map
 programs, pmean/psum under vmap-style axes) appear as eqns; the psums
 GSPMD *inserts* during SPMD partitioning of an auto-sharded jit do
-not exist at trace time and are invisible here — consistently with
-the runtime side, which never attributes them either (the traffic
+not exist at trace time and are invisible to ``extract`` — consistently
+with the runtime side, which never attributes them either (the traffic
 plane charges through wrapper-level note models and the audited
 dispatch layer, both of which run outside XLA's partitioner).  Both
 ledgers therefore cover the same program: the explicitly-dispatched
-collectives.
+collectives.  ``from_compiled`` sees the partitioned program instead:
+GSPMD's all-reduces, the resharding it inserts and the explicit
+collectives alike, after XLA has combined and scheduled them.
 """
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -332,6 +339,19 @@ class CommGraph:
         return sum(r.total_bytes for r in self.records
                    if r.path.startswith("reshard-plan"))
 
+    def wire_by_axes(self, mesh) -> Dict[Tuple[str, ...], int]:
+        """Static wire bytes per group of mesh axes, each record priced
+        by the models above (psum ring, ppermute, all_to_all, gather /
+        scatter); pmin/pmax and scalar payloads are not priced."""
+        out: Dict[Tuple[str, ...], int] = {}
+        for ax in dict.fromkeys(r.axes for r in self.records):
+            sub = CommGraph(records=[r for r in self.records
+                                     if r.axes == ax])
+            out[ax] = (sub.psum_ring_bytes(mesh) + sub.ppermute_bytes()
+                       + sub.all_to_all_bytes()
+                       + sub.gather_scatter_bytes(mesh))
+        return out
+
 
 # ---------------------------------------------------------------------------
 # extraction
@@ -456,6 +476,149 @@ def from_reshard_plan(plan) -> CommGraph:
             shape=(), nbytes=int(step.wire_bytes), trips=1,
             perm=tuple(tuple(int(x) for x in p) for p in step.perm),
             path=f"reshard-plan/step{i}:{step.describe()}"))
+    return g
+
+
+# HLO opcode -> canonical op (an all-reduce's is refined by its reducer)
+_HLO_COLL = {"all-reduce": "psum", "all-gather": "all_gather",
+             "reduce-scatter": "reduce_scatter", "all-to-all": "all_to_all",
+             "collective-permute": "ppermute"}
+_HLO_REDUCER = {"maximum": "pmax", "minimum": "pmin"}
+_HLO_DTYPES = {"pred": "bool", "s8": "int8", "s16": "int16", "s32": "int32",
+               "s64": "int64", "u8": "uint8", "u16": "uint16",
+               "u32": "uint32", "u64": "uint64", "f16": "float16",
+               "bf16": "bfloat16", "f32": "float32", "f64": "float64",
+               "f8e4m3fn": "float8_e4m3fn", "f8e5m2": "float8_e5m2"}
+_HLO_ITEMSIZE = {"bool": 1, "bfloat16": 2, "float8_e4m3fn": 1,
+                 "float8_e5m2": 1}
+_HLO_COMP = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{\s*$")
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) (all-reduce|all-gather|"
+    r"reduce-scatter|all-to-all|collective-permute)(-start)?\(")
+_HLO_ARRAY = re.compile(r"\b([a-z][a-z0-9]*)\[([0-9,]*)\]")
+_HLO_CALLEE = re.compile(r"(?:calls|to_apply|body|condition|"
+                         r"branch_computations)=(\{[^}]*\}|%[\w.\-]+)")
+_HLO_GROUPS_LIST = re.compile(r"replica_groups=\{((?:\{[\d,]*\},?)*)\}")
+_HLO_GROUPS_IOTA = re.compile(
+    r"replica_groups=\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?")
+_HLO_PAIRS = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
+
+
+def _hlo_groups(line: str, n_devices: int) -> List[List[int]]:
+    """Device groups of one collective instruction (logical device ids,
+    which index the mesh's devices in order)."""
+    m = _HLO_GROUPS_IOTA.search(line)
+    if m:
+        shape = [int(x) for x in m.group(1).split(",")]
+        dims = [int(x) for x in m.group(2).split(",")]
+        ids = np.arange(int(np.prod(dims))).reshape(dims)
+        if m.group(3):
+            ids = ids.transpose([int(x) for x in m.group(3).split(",")])
+        return ids.reshape(shape).tolist()
+    m = _HLO_GROUPS_LIST.search(line)
+    if m and m.group(1):
+        return [[int(x) for x in g.split(",") if x]
+                for g in re.findall(r"\{([\d,]*)\}", m.group(1))]
+    m = _HLO_PAIRS.search(line)
+    if m:
+        return [[int(a), int(b)] for a, b in
+                re.findall(r"\{(\d+),(\d+)\}", m.group(1))]
+    return [list(range(n_devices))]
+
+
+def _hlo_axes(groups: List[List[int]], shape: Tuple[int, ...],
+              names: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The mesh axes along which the members of the groups differ."""
+    varying = set()
+    for g in groups:
+        coords = np.array(np.unravel_index(np.asarray(g), shape))
+        varying |= {i for i in range(len(shape))
+                    if len(set(coords[i].tolist())) > 1}
+    return tuple(names[i] for i in sorted(varying))
+
+
+def from_compiled(compiled, mesh, source: str = "") -> CommGraph:
+    """Lift the collectives of a compiled, partitioned program into a
+    CommGraph.  ``compiled`` is a ``jax.stages.Compiled`` (or its HLO
+    text) and ``mesh`` the mesh its shardings name.  One record per
+    payload array of each collective instruction: ``axes`` are the mesh
+    axes its replica groups (source-target pairs, for a permute) span,
+    ``nbytes`` the per-shard operand bytes, ``path`` the computation
+    and instruction name, as the profiler's op events carry it.  An
+    instruction runs once per call (``trips`` 1) unless it sits under a
+    while loop, where it is marked unbounded."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    sizes = _axis_sizes(mesh)
+    names, shape = tuple(sizes), tuple(sizes.values())
+    n_dev = int(np.prod(shape)) if shape else 1
+    bodies, callees, reducers = {}, {}, {}
+    comp = ""
+    for line in text.splitlines():
+        m = _HLO_COMP.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        if " = " not in line:
+            continue
+        bodies.setdefault(comp, []).append(line)
+        for val in _HLO_CALLEE.findall(line):
+            callees.setdefault(comp, set()).update(
+                re.findall(r"%([\w.\-]+)", val))
+        if line.lstrip().startswith("ROOT"):
+            op = re.search(r"\s([a-z][\w\-]*)\(", line.split(" = ", 1)[1])
+            reducers[comp] = _HLO_REDUCER.get(op and op.group(1), "psum")
+    # computations run under a while loop: its body and all it calls
+    looped, todo = set(), [b for ls in bodies.values() for ln in ls
+                           for b in re.findall(r"body=%([\w.\-]+)", ln)]
+    while todo:
+        c = todo.pop()
+        if c not in looped:
+            looped.add(c)
+            todo.extend(callees.get(c, ()))
+    g = CommGraph(source=source or "compiled")
+    for comp, lines in bodies.items():
+        for line in lines:
+            m = _HLO_INSTR.match(line)
+            if m is None:
+                continue
+            name, result, opcode, start = m.groups()
+            op = _HLO_COLL[opcode]
+            if op == "psum":
+                red = re.search(r"to_apply=%([\w.\-]+)", line)
+                op = reducers.get(red.group(1), "psum") if red else op
+            groups = _hlo_groups(line, n_dev)
+            axes = _hlo_axes(groups, shape, names) if shape else ()
+            arrays = [(_HLO_DTYPES.get(dt, dt),
+                       tuple(int(x) for x in dims.split(",") if x))
+                      for dt, dims in _HLO_ARRAY.findall(result)]
+            n = max(len(grp) for grp in groups)
+            if start and opcode != "all-reduce":
+                # (operands, outputs[, u32 contexts]): keep the operands
+                arrays = [a for a in arrays if a[1]] or arrays
+                arrays = arrays[:max(1, len(arrays) // 2)]
+                result_shaped = False
+            else:
+                result_shaped = True
+            perm = ()
+            if op == "ppermute" and axes:
+                pos = [names.index(a) for a in axes]
+                perm = tuple(sorted({
+                    tuple(int(np.ravel_multi_index(
+                        [np.unravel_index(i, shape)[p] for p in pos],
+                        [shape[p] for p in pos])) for i in pair)
+                    for pair in groups}))
+            for dt, shp in arrays:
+                count = int(np.prod(shp)) if shp else 1
+                item = _HLO_ITEMSIZE.get(dt) or np.dtype(dt).itemsize
+                nbytes = count * item
+                if result_shaped and op == "all_gather":
+                    nbytes //= n
+                elif result_shaped and op == "reduce_scatter":
+                    nbytes *= n
+                g.records.append(CollRecord(
+                    op=op, axes=axes, dtype=dt, shape=shp, nbytes=nbytes,
+                    trips=1, perm=perm, path=f"{comp}/{name}",
+                    bounded=comp not in looped))
     return g
 
 

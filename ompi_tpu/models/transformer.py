@@ -26,7 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
 
+from .. import trace
 from ..parallel.ring import attention_reference, ring_attention
 
 
@@ -714,7 +716,17 @@ def make_train_step(cfg: Config, mesh: Optional[Mesh] = None,
                      mu_dtype=jnp.dtype(cfg.opt_moment_dtype))
 
     def init_opt(params):
-        return tx.init(params)
+        state = tx.init(params)
+        if mesh is None:
+            return state
+        # the step count is made on one device; the step returns it
+        # replicated over the mesh, and a second sharding of the same
+        # argument would compile the whole step a second time
+        rep = NamedSharding(mesh, P())
+        return jax.tree.map(
+            lambda x: jax.device_put(x, rep) if x.ndim == 0 and isinstance(
+                getattr(x, "sharding", None), SingleDeviceSharding) else x,
+            state)
 
     _MODES = ("native", "quant", "perleaf", "bucketed", "unsynced")
     if cfg.grad_sync not in _MODES:
@@ -769,6 +781,10 @@ def make_train_step(cfg: Config, mesh: Optional[Mesh] = None,
 
     fpt = train_flops_per_token(cfg)
 
+    def dispatch(params, opt_state, tokens):
+        with trace.region("ompi.train.step"):
+            return jstep(params, opt_state, tokens)
+
     def timed_step(params, opt_state, tokens):
         from .. import numerics, perf
         if isinstance(tokens, jax.core.Tracer):
@@ -778,16 +794,16 @@ def make_train_step(cfg: Config, mesh: Optional[Mesh] = None,
                 # per-step loss telemetry for the NUMERICS ledger (the
                 # grad norm comes from the overlap.vg hook; record_step
                 # pairs them on the step row and advances the counter)
-                out = jstep(params, opt_state, tokens)
+                out = dispatch(params, opt_state, tokens)
                 numerics.record_step(loss=float(out[2]))
                 return out
-            return jstep(params, opt_state, tokens)
+            return dispatch(params, opt_state, tokens)
         # goodput/MFU ledger: blocked wall per step. Only wall + token
         # FLOPs are measurable from one blocked call — the comm split
         # (exposed vs total) comes from the bench goodput probe's
         # unsynced-floor methodology, never fabricated here.
         t0 = time.perf_counter()
-        out = jstep(params, opt_state, tokens)
+        out = dispatch(params, opt_state, tokens)
         jax.block_until_ready(out)
         perf.record_step(time.perf_counter() - t0,
                          tokens=tokens.shape[0] * max(tokens.shape[1] - 1,
@@ -798,5 +814,15 @@ def make_train_step(cfg: Config, mesh: Optional[Mesh] = None,
             numerics.record_step(loss=float(out[2]))
         return out
 
+    def comm_graph(params, opt_state, tokens):
+        """The step's collectives as compiled for these arguments
+        (``analysis.commgraph.from_compiled``): per mesh axis, what
+        GSPMD inserted and XLA kept.  It lowers and compiles the step
+        again, which the compile cache serves once the step has run."""
+        from ..analysis.commgraph import from_compiled
+        return from_compiled(jstep.lower(params, opt_state, tokens).compile(),
+                             mesh, source="train_step")
+
     timed_step.jitted = jstep       # for .lower()/.compile() inspection
+    timed_step.comm_graph = comm_graph
     return init_opt, timed_step

@@ -549,3 +549,73 @@ class TestRulesValidator:
         bad.write_text("allreduce 1 0 native\nallreduce 1 0 hier\n")
         assert rules.main([str(good)]) == 0
         assert rules.main([str(bad)]) == 1
+
+
+_HLO = """HloModule jit_step
+
+%add.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %s = f32[] add(f32[] %a, f32[] %b)
+}
+
+%max.2 (a: bf16[], b: bf16[]) -> bf16[] {
+  %a = bf16[] parameter(0)
+  %b = bf16[] parameter(1)
+  ROOT %m = bf16[] maximum(bf16[] %a, bf16[] %b)
+}
+
+%body.3 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %all-reduce.7 = f32[8]{0} all-reduce(f32[8]{0} %p), channel_id=9, replica_groups={{0,1,2,3}}, use_global_device_ids=true, to_apply=%add.1
+}
+
+ENTRY %main.4 (x: f32[4,8]) -> f32[8] {
+  %x = f32[4,8]{1,0} parameter(0)
+  %all-reduce.1 = (bf16[4,8]{1,0:T(8,128)(2,1)}, f32[16]{0}) all-reduce(%c, %d), channel_id=1, replica_groups=[2,2]<=[4], use_global_device_ids=true, to_apply=%add.1
+  %all-reduce.2 = bf16[4]{0} all-reduce(%e), channel_id=2, replica_groups={{0,2},{1,3}}, use_global_device_ids=true, to_apply=%max.2
+  %all-gather.3 = f32[8,8]{1,0} all-gather(%x), channel_id=3, replica_groups=[2,2]<=[2,2]T(1,0), dimensions={0}, use_global_device_ids=true
+  %reduce-scatter.4 = f32[2,8]{1,0} reduce-scatter(%x), channel_id=4, replica_groups=[2,2]<=[4], dimensions={0}, use_global_device_ids=true, to_apply=%add.1
+  %collective-permute-start.5 = (bf16[4,8]{1,0}, bf16[4,8]{1,0}, u32[], u32[]) collective-permute-start(%c), channel_id=5, source_target_pairs={{0,1},{1,0},{2,3},{3,2}}
+  %collective-permute-done.5 = bf16[4,8]{1,0} collective-permute-done(%collective-permute-start.5)
+  %all-gather-start.6 = (f32[4]{0}, f32[8]{0}) all-gather-start(%f), channel_id=6, replica_groups=[2,2]<=[4], dimensions={0}, use_global_device_ids=true
+  %all-to-all.8 = bf16[1,2,4]{2,1,0} all-to-all(%g), channel_id=8, replica_groups=[2,2]<=[4], dimensions={1}
+  ROOT %while.9 = f32[8]{0} while(f32[8]{0} %h), condition=%cond.5, body=%body.3
+}
+"""
+
+
+def test_from_compiled_reads_partitioned_hlo():
+    """Groups in iota, transposed-iota and list form and a permute's
+    pairs map to mesh axes; payloads are per-shard operand bytes; a
+    combined all-reduce gives one record per operand; the reducer names
+    pmax; -done halves are not counted; a loop body's collective is
+    unbounded."""
+    g = commgraph.from_compiled(_HLO, {"dp": 2, "tp": 2}, source="t")
+    got = [(r.path, r.op, r.axes, r.dtype, r.shape, r.nbytes, r.bounded)
+           for r in g.records]
+    assert sorted(got) == sorted([
+        ("body.3/all-reduce.7", "psum", ("dp", "tp"), "float32", (8,), 32,
+         False),
+        ("main.4/all-reduce.1", "psum", ("tp",), "bfloat16", (4, 8), 64,
+         True),
+        ("main.4/all-reduce.1", "psum", ("tp",), "float32", (16,), 64, True),
+        ("main.4/all-reduce.2", "pmax", ("dp",), "bfloat16", (4,), 8, True),
+        ("main.4/all-gather.3", "all_gather", ("dp",), "float32", (8, 8),
+         128, True),
+        ("main.4/reduce-scatter.4", "reduce_scatter", ("tp",), "float32",
+         (2, 8), 128, True),
+        ("main.4/collective-permute-start.5", "ppermute", ("tp",),
+         "bfloat16", (4, 8), 64, True),
+        ("main.4/all-gather-start.6", "all_gather", ("tp",), "float32", (4,),
+         16, True),
+        ("main.4/all-to-all.8", "all_to_all", ("tp",), "bfloat16", (1, 2, 4),
+         16, True),
+    ])
+    perm = next(r for r in g.records if r.op == "ppermute").perm
+    assert perm == ((0, 1), (1, 0))           # positions along tp
+    assert [i.kind for i in g.check({"dp": 2, "tp": 2})] == ["unbounded"]
+    # psum ring 2(n-1)/n, ppermute and all_to_all 1x, all_gather (n-1)x
+    # the shard, reduce_scatter (n-1)/n; pmax is not priced
+    assert g.wire_by_axes({"dp": 2, "tp": 2}) == {
+        ("dp", "tp"): 48, ("tp",): 128 + 64 + 16 + 16 + 64, ("dp",): 128}

@@ -102,3 +102,59 @@ def test_device_comm_collectives_four_chips(topo):
     assert "all-reduce" in hlo and "all-to-all" in hlo
     assert compiled.memory_analysis().argument_size_in_bytes == (
         4 * (1 << 16) * 4)                          # one row per chip
+
+
+def test_dp_tp_step_moves_what_the_yardstick_counts(topo):
+    """The dp2 x tp2 train step compiled for the described 2x2 host: the
+    program's own view of its collectives (``step.comm_graph``, read from
+    the partitioned HLO) against ``benchmark/comm_bytes.py``.  Megatron's
+    tensor-parallel all-reduces are exactly the yardstick's; the dp
+    gradient all-reduce is the yardstick's plus one more copy of the
+    embedding shard's gradient (XLA reduces the tied embedding's lookup
+    and head contributions apart); the rest of the tp traffic is the
+    resharding of the fused QKV projection, which the yardstick does not
+    count: its column halves are not whole heads."""
+    import optax
+
+    from benchmark import comm_bytes
+    from ompi_tpu.models.transformer import (Config, init_params,
+                                             make_train_step, param_specs)
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("dp", "tp"))
+    cfg = Config(vocab=1024, d_model=512, n_layers=2, n_heads=4,
+                 head_dim=128, d_ff=1024, seq=256, attn="flash",
+                 remat="dots")
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.key(0))
+    params = jax.tree.map(
+        lambda x, s: _sds(x.shape, x.dtype, NamedSharding(mesh, s)),
+        shapes, param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
+    init_opt, step = make_train_step(cfg, mesh)
+    rep = NamedSharding(mesh, P())
+    opt = tuple(
+        optax.ScaleByAdamState(count=_sds((), o.count.dtype, rep),
+                               mu=params, nu=params)
+        if isinstance(o, optax.ScaleByAdamState) else o
+        for o in jax.eval_shape(init_opt, shapes))
+    tokens = _sds((4, cfg.seq + 1), jnp.int32,
+                  NamedSharding(mesh, P("dp", None)))
+    g = step.comm_graph(params, opt, tokens)
+    assert not [i for i in g.check(mesh) if i.severity == "error"]
+    assert all(r.bounded and r.trips == 1 for r in g.records)
+
+    def payload(axes, ops):
+        return sum(r.nbytes for r in g.records
+                   if r.axes == axes and r.op in ops and not r.control)
+
+    want_tp = comm_bytes.tp_payload(cfg.d_model, cfg.n_layers, rows=2,
+                                    seq=cfg.seq)
+    assert payload(("tp",), ("psum", "pmax")) == want_tp
+    embed_shard = cfg.vocab // 2 * cfg.d_model * 2
+    want_dp = comm_bytes.dp_payload(cfg.d_model, cfg.n_layers, cfg.n_heads,
+                                    cfg.head_dim, cfg.d_ff, cfg.vocab, tp=2)
+    assert payload(("dp",), ("psum",)) == want_dp + embed_shard
+    assert {r.op for r in g.records} <= {"psum", "pmax", "ppermute",
+                                         "all_to_all"}
+    assert {r.axes for r in g.records} == {("tp",), ("dp",)}
+    wire = g.wire_by_axes(mesh)
+    assert wire[("dp",)] == want_dp + embed_shard
+    assert wire[("tp",)] >= want_tp

@@ -273,3 +273,21 @@ def test_allreduce_split_sums_to_its_total(tmp_path):
     parts = op["self_s"] + sum(r[p]["total_s"] for p in COLL_PARTS)
     assert parts == pytest.approx(op["total_s"], rel=0.01)
     assert all(r[p]["total_s"] > 0 for p in COLL_PARTS)
+
+
+def test_train_step_region(recording):
+    """``make_train_step``'s timed path: one ``ompi.train.step`` region per
+    dispatched step, on a mesh and off it; a step traced inside another
+    jit dispatches nothing and records none."""
+    tok = jnp.zeros((4, 17), jnp.int32)
+    mesh = make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    for m in (None, mesh):
+        params = tfm.init_params(jax.random.PRNGKey(0), CFG)
+        if m is not None:
+            params = tfm.shard_params(params, m, CFG)
+        init_opt, step = tfm.make_train_step(CFG, m)
+        opt = init_opt(params)
+        for _ in range(2):
+            params, opt, _loss = step(params, opt, tok)
+        jax.jit(lambda p, o, t: step(p, o, t)[2])(params, opt, tok)
+    assert trace.regions()["ompi.train.step"]["count"] == 4
