@@ -187,7 +187,12 @@ def init_params(rng: jax.Array, cfg: Config) -> Dict:
 def param_specs(cfg: Config) -> Dict:
     """Megatron-style TP layout: qkv/gate/up column-parallel (shard the
     output features over `tp`), wo/down row-parallel (shard the input
-    features; XLA inserts the psum). Embedding sharded over vocab."""
+    features; XLA inserts the psum). Embedding sharded over vocab.
+
+    The fused ``wqkv`` leaf stays canonical, ``[q | k | v]`` along its
+    columns, so its tp column shards are not whole heads; on a tp mesh
+    the projection slices the leaf into head-aligned q, k and v blocks
+    (``_qkv_blocks``) rather than the leaf being laid out otherwise."""
     layer = {
         "attn_norm": P(),
         "wqkv": P(None, "tp"),
@@ -455,8 +460,14 @@ def _attn_apply(x: jax.Array, layer: Dict, cfg: Config,
     b, s = x.shape[0], x.shape[1]
     positions = jnp.arange(s)
     h = _rms_norm(x, layer["attn_norm"])
-    qkv = h @ layer["wqkv"].astype(cfg.dtype)          # (b, s, 3*heads*hd)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    if isinstance(layer["wqkv"], tuple):
+        # on a tp mesh: the column halves of the fused [q | k | v] are
+        # not whole heads, so _qkv_blocks sliced it into head-aligned
+        # q, k and v blocks
+        q, k, v = (h @ w for w in layer["wqkv"])
+    else:
+        qkv = h @ layer["wqkv"].astype(cfg.dtype)      # (b, s, 3*heads*hd)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.n_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.n_heads, cfg.head_dim)
@@ -474,6 +485,25 @@ def _attn_apply(x: jax.Array, layer: Dict, cfg: Config,
         att = attention_reference(q, k, v, causal=True)
     att = att.reshape(b, s, cfg.n_heads * cfg.head_dim)
     return x + att @ layer["wo"].astype(cfg.dtype)     # row-parallel → psum
+
+
+def _qkv_blocks(layer: Dict, cfg: Config, mesh: Optional[Mesh]) -> Dict:
+    """On a mesh with tp > 1, the layer with ``wqkv`` replaced by its
+    bf16 q, k and v column blocks, each constrained head-sharded over
+    tp: GSPMD then moves weight blocks where projecting with the whole
+    leaf would reshuffle the q, k and v activations every layer
+    (at tp 2 one chip holds all of q and half of k).  Elsewhere the
+    layer as it is.  Called outside the checkpointed layer, so remat
+    does not move the blocks again in the backward."""
+    if (mesh is None or mesh.shape.get("tp", 1) == 1
+            or cfg.tp_overlap == "fused"):
+        return layer
+    w = layer["wqkv"].astype(cfg.dtype)
+    n = cfg.n_heads * cfg.head_dim
+    col = NamedSharding(mesh, P(None, "tp"))
+    return dict(layer, wqkv=tuple(
+        lax.with_sharding_constraint(w[:, i * n:(i + 1) * n], col)
+        for i in range(3)))
 
 
 def _layer_apply(x: jax.Array, layer: Dict, cfg: Config,
@@ -521,7 +551,7 @@ def _backbone(params: Dict, tokens: jax.Array, cfg: Config,
     layer_fn = _remat_wrap(
         lambda x, layer: _layer_apply(x, layer, cfg, mesh), cfg.remat)
     for layer in params["layers"]:
-        x, aux = layer_fn(x, layer)
+        x, aux = layer_fn(x, _qkv_blocks(layer, cfg, mesh))
         aux_total = aux_total + aux
     return _rms_norm(x, params["final_norm"]), aux_total
 

@@ -11,6 +11,7 @@ one process may hold libtpu, and a module that decides at import whether
 its tests exist gives pytest-xdist workers different collections.
 """
 
+import dataclasses
 import os
 import re
 
@@ -111,9 +112,12 @@ def test_dp_tp_step_moves_what_the_yardstick_counts(topo):
     tensor-parallel all-reduces are exactly the yardstick's; the dp
     gradient all-reduce is the yardstick's plus one more copy of the
     embedding shard's gradient (XLA reduces the tied embedding's lookup
-    and head contributions apart); the rest of the tp traffic is the
-    resharding of the fused QKV projection, which the yardstick does not
-    count: its column halves are not whole heads."""
+    and head contributions apart).  The rest of the tp traffic moves
+    blocks of the fused QKV weight, which the yardstick does not count:
+    its column halves are not whole heads, so the step projects from
+    head-aligned blocks of it.  No activation is reshuffled: no
+    all-to-all, every permute has a weight's shape, and those bytes stay
+    put when the rows or the sequence double."""
     import optax
 
     from benchmark import comm_bytes
@@ -128,20 +132,24 @@ def test_dp_tp_step_moves_what_the_yardstick_counts(topo):
     params = jax.tree.map(
         lambda x, s: _sds(x.shape, x.dtype, NamedSharding(mesh, s)),
         shapes, param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
-    init_opt, step = make_train_step(cfg, mesh)
     rep = NamedSharding(mesh, P())
-    opt = tuple(
-        optax.ScaleByAdamState(count=_sds((), o.count.dtype, rep),
-                               mu=params, nu=params)
-        if isinstance(o, optax.ScaleByAdamState) else o
-        for o in jax.eval_shape(init_opt, shapes))
-    tokens = _sds((4, cfg.seq + 1), jnp.int32,
-                  NamedSharding(mesh, P("dp", None)))
-    g = step.comm_graph(params, opt, tokens)
+
+    def comm_graph(cfg, rows):
+        init_opt, step = make_train_step(cfg, mesh)
+        opt = tuple(
+            optax.ScaleByAdamState(count=_sds((), o.count.dtype, rep),
+                                   mu=params, nu=params)
+            if isinstance(o, optax.ScaleByAdamState) else o
+            for o in jax.eval_shape(init_opt, shapes))
+        tokens = _sds((rows, cfg.seq + 1), jnp.int32,
+                      NamedSharding(mesh, P("dp", None)))
+        return step.comm_graph(params, opt, tokens)
+
+    g = comm_graph(cfg, 4)
     assert not [i for i in g.check(mesh) if i.severity == "error"]
     assert all(r.bounded and r.trips == 1 for r in g.records)
 
-    def payload(axes, ops):
+    def payload(axes, ops, g=g):
         return sum(r.nbytes for r in g.records
                    if r.axes == axes and r.op in ops and not r.control)
 
@@ -158,3 +166,19 @@ def test_dp_tp_step_moves_what_the_yardstick_counts(topo):
     wire = g.wire_by_axes(mesh)
     assert wire[("dp",)] == want_dp + embed_shard
     assert wire[("tp",)] >= want_tp
+
+    tp_records = [r for r in g.records if r.axes == ("tp",)]
+    assert not [r for r in tp_records if r.op == "all_to_all"]
+    permutes = [r for r in tp_records if r.op == "ppermute"]
+    assert permutes
+    assert all(r.shape[0] == cfg.d_model for r in permutes), permutes
+
+    def weight_moves(g):
+        return sum(r.nbytes for r in g.records if r.axes == ("tp",)
+                   and r.op not in ("psum", "pmax") and not r.control)
+
+    moved = weight_moves(g)
+    assert moved > 0
+    twice_seq = dataclasses.replace(cfg, seq=2 * cfg.seq)
+    assert weight_moves(comm_graph(cfg, 8)) == moved
+    assert weight_moves(comm_graph(twice_seq, 4)) == moved

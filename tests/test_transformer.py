@@ -87,6 +87,47 @@ def test_training_flash_remat_reduces_loss(remat):
     assert losses[-1] < losses[0] * 0.8, f"no learning: {losses}"
 
 
+def test_dp_tp_flash_step_matches_one_device():
+    """dp 2 x tp 2, flash under "dots" remat, float32: the step that
+    projects q, k and v from head-aligned blocks of wqkv takes the same
+    three steps as one device's fused projection, and hands the fused
+    leaf back as it stores it: (d, 3h), column-sharded over tp."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    cfg = Config(vocab=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
+                 d_ff=64, seq=32, attn="flash", remat="dots",
+                 dtype=jnp.float32)
+    rng = np.random.default_rng(3)
+    batches = [jnp.asarray(rng.integers(0, cfg.vocab, (4, cfg.seq + 1)),
+                           jnp.int32) for _ in range(3)]
+    mesh = make_mesh({"dp": 2, "tp": 2}, jax.devices()[:4])
+
+    def three_steps(mesh):
+        params = init_params(jax.random.key(3), cfg)
+        if mesh is not None:
+            params = shard_params(params, mesh, cfg)
+        init_opt, step = make_train_step(cfg, mesh)
+        opt = init_opt(params)
+        losses = []
+        for toks in batches:
+            params, opt, loss = step(params, opt, toks)
+            losses.append(float(loss))
+        return losses, params
+
+    one_losses, one = three_steps(None)
+    mesh_losses, sharded = three_steps(mesh)
+    np.testing.assert_allclose(mesh_losses, one_losses, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(one)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    h = cfg.n_heads * cfg.head_dim
+    for layer in sharded["layers"]:
+        w = layer["wqkv"]
+        assert w.shape == (cfg.d_model, 3 * h)
+        assert w.sharding.is_equivalent_to(
+            NamedSharding(mesh, P(None, "tp")), w.ndim)
+
+
 def test_ring_and_dense_forward_agree():
     mesh = make_mesh({"dp": 1, "sp": 8, "tp": 1})
     cfg_ring = Config(vocab=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
