@@ -9,32 +9,9 @@ SHELL := /bin/bash
 	health-tests perf-tests traffic-tests hier-tests numerics-tests \
 	reshard-tests analysis-tests ft-elastic-tests moe-tests \
 	serve-tests decode-tests policy-tests fleet-tests request-tests \
-	history-tests comm-lint bench-compare
+	history-tests comm-lint
 
-# the health-plane gate runs FIRST: its suite is seconds-cheap and its
-# end-to-end probe (an 8-rank fleet with an injected one-rank stall the
-# watchdog must attribute within 2x its timeout) guards the tier the
-# rest of the run leans on when something hangs; the perf-plane gate
-# rides along — its suite is also seconds-cheap and its probe banks the
-# trajectory artifact bench-compare diffs against; the traffic-plane
-# gate closes the loop — its probe injects a skewed ppermute an 8-dev
-# fleet's matrix must attribute to the exact hot edge, conservation held;
-# the hier gate rides last — its probe folds the 8 devices into a
-# simulated 2x4 ICI×DCN pod and fails unless the hier arm beats flat
-# wall-clock while moving exactly 1/n_inner of the bytes on the slow
-# plane; the numerics gate watches the payload itself — its probe
-# injects a NaN and a bit flip the plane must attribute to the exact
-# (rank, step, op) / (step, bucket, rank); the reshard gate closes the
-# sequence — its probe times a 4-transition layout-conversion suite
-# against the host round-trip it replaces and fails unless the device
-# plans win with every step decision-audited and conservation held;
-# the analysis gate runs before any of it — the static verifier and
-# comm-lint are pure CPU/AST work that catches a malformed collective
-# program or an unaudited dispatch path without spending a single
-# measured second
-tier1: analysis-tests health-tests perf-tests traffic-tests hier-tests \
-	numerics-tests reshard-tests ft-elastic-tests moe-tests serve-tests \
-	decode-tests policy-tests fleet-tests request-tests history-tests
+tier1:
 	set -o pipefail; rm -f /tmp/_t1.log; \
 	timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
 	  -m 'not slow' --continue-on-collection-errors \
@@ -58,190 +35,99 @@ trace-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_observability.py \
 	  -q -k "trace or wire or handle" -p no:cacheprovider -p no:randomly
 
-# the fleet flight-recorder tier: cross-rank merge, straggler doctor,
-# mpisync, Prometheus exposition — then the end-to-end probe (an 8-rank
-# fleet with an injected straggler the doctor must attribute)
+# the fleet flight-recorder suite: cross-rank merge, straggler doctor,
+# mpisync, Prometheus exposition
 doctor-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_doctor.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --doctor
 
-# the live-health tier: watchdog + desync sentinel + HTTP endpoint
-# suite, then the end-to-end stall-attribution probe (exits nonzero
-# unless the sentinel names the stalled rank and dumps land)
+# the live-health suite: watchdog, desync sentinel, HTTP endpoint
 health-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_health.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --watchdog
 
-# the continuous-performance tier: cost model + goodput ledger + sentry
-# suite, then the end-to-end probe (measures the goodput split through
-# the unsynced-floor methodology, banks BENCH_r06.json and the
-# PERF_LEDGER, exits nonzero on unmeasured columns)
+# the continuous-performance suite: cost model, goodput ledger, sentry
 perf-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_perf.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --goodput
 
-# the topology-traffic tier: per-edge attribution + ICI/DCN plane
-# ledger + hot-link sentry suite, then the end-to-end probe (uniform
-# ring background plus a skewed push_row lane the sentry must trip on
-# EXACTLY once, naming (src, dst); banks TRAFFIC_<platform>.json; exits
-# nonzero on any conservation residue)
+# the topology-traffic suite: per-edge attribution, ICI/DCN plane
+# ledger, hot-link sentry
 traffic-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_traffic.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --traffic
 
-# the hierarchical multi-plane tier: hier/hier+quant decision arms,
-# '<coll>@<plane>' rule rows, padding fix, simulated-DCN classification
-# — then the end-to-end pod probe (8 devices as a 2x4 outer×inner mesh
-# with the outer axis DCN-skewed; exits nonzero unless hier beats flat
-# and the outer stage carries exactly 1/n_inner of the flat-arm bytes)
+# the hierarchical multi-plane suite: hier/hier+quant decision arms,
+# '<coll>@<plane>' rule rows, simulated-DCN classification
 hier-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_hier.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --pod
 
-# the numerics tier: probes/sentries/divergence-auditor suite, then the
-# end-to-end probe (8-dev comm with an injected NaN + a bit-flipped
-# replica; exits nonzero unless both are attributed to exactly the
-# injected (rank, step, op) and (step, bucket, rank); banks
-# NUMERICS_<platform>.json)
+# the numerics suite: probes, sentries, divergence auditor
 numerics-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_numerics.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --numerics
 
-# the redistribution tier: plan compiler + executable cache + audit
-# suite, then the end-to-end probe (8 devices; a 4-transition 32 MiB
-# layout-conversion suite timed against the staged host round-trip it
-# replaces; exits nonzero unless the device plans win wall-clock, every
-# plan stays within its peak-bytes bound, every step emitted exactly
-# one decide:reshard event, and the traffic matrix's reshard bytes
-# equal the audited wire bytes; banks RESHARD_<platform>.json)
+# the redistribution suite: plan compiler, executable cache, audit
 reshard-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_reshard.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --reshard
 
-# the elastic fault-tolerance tier: cross-mesh reshard planner +
-# peer-shadow ring + ElasticTrainer recovery loop + chaos injector
-# suite, then the end-to-end probe (8 devices; a deterministic kill of
-# mesh position 3 at step 7 the trainer must survive by shrinking to
-# the 4-device mesh and re-laying state from the peer shadows with ZERO
-# checkpoint reads; exits nonzero unless the injected rank is named by
-# exactly one audited ft_recovery decision, recovery lands within the
-# steps-lost budget, the losses track an uninterrupted baseline, and
-# traffic conservation holds; banks ELASTIC_<platform>.json)
+# the elastic fault-tolerance suite: cross-mesh reshard planner,
+# peer-shadow ring, ElasticTrainer recovery loop, chaos injector
 ft-elastic-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_elastic.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --elastic
 
-# the token-proportional MoE tier: ragged dispatch/combine round-trip
-# vs the host oracle + moe_block_ep arm/conservation suite + hot-expert
-# sentry/adaptation loop, then the end-to-end probe (8 devices, einsum
-# vs ragged vs ragged+hier on uniform AND skewed routing; exits nonzero
-# unless the skewed phase trips the hot-expert sentry EXACTLY once, a
-# capacity adaptation rebalances it away within the probe, ragged wire
-# bytes stay token-proportional, and traffic conservation holds; banks
-# MOE_<platform>.json + a BASELINE.md row)
+# the token-proportional MoE suite: ragged dispatch/combine against the
+# host oracle, moe_block_ep arms and conservation, hot-expert sentry
 moe-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_moe_ep.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --moe
 
-# the serving tier: paged-KV-cache accounting + prefill/decode greedy
-# parity vs the train forward() + convert_params round-trip with the
-# per-weight reshard plan pinned + continuous-vs-static scheduler +
-# decode_ag/decode_rs decision audit/conservation suite, then the
-# end-to-end probe (8 devices, one Poisson stream through both
-# batching policies + a teacher-forced native-vs-int8 window; exits
-# nonzero unless continuous beats static on tokens/s with identical
-# per-request outputs, quant shrinks decode wire >= 3x at parity, and
-# every audited byte conserves; banks SERVE_<platform>.json +
-# BASELINE.md rows)
+# the serving suite: paged-KV-cache accounting, prefill/decode greedy
+# parity against the train forward(), convert_params, continuous-vs-
+# static scheduler, decode_ag/decode_rs decision audit and conservation
 serve-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --serve
 
-# the decode fast-path tier: fused collective-matmul decode program
-# (eager-vs-fused parity, 11 -> 2 eager dispatches/step, commgraph
-# static-vs-runtime byte proof on 2/4/8-dev meshes) + speculative
-# draft/verify windows (token-stream identity, MEASURED acceptance) +
-# pad-past-native quant veto + learned decode arms + MoE decode parity
-# + comm-lint over the serving modules; the --serve probe's fused/
-# speculative/learned phases are its end-to-end gate (shares the
-# serve-tests probe so the banked SERVE_<platform>.json stays one
-# artifact)
+# the decode fast-path suite: fused collective-matmul decode program,
+# speculative draft/verify windows, pad-past-native quant veto, learned
+# decode arms, MoE decode parity
 decode-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_decode.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --serve
 
-# the policy-plane tier: verdict bus + statically pre-verified action
-# space + fleet-consistent vote + audited observe->decide->act suite,
-# then the self-driving probe (8 devices; a chaos-slowed allreduce link
-# plus a forced quant-SNR drop the plane must retune PAST without a
-# restart — exits nonzero unless the arm demotes to quant fleet-wide,
-# recovered goodput beats the degraded floor under the SAME chaos,
-# zero steps drop, every decide:policy event names its causing verdict
-# (100% attribution) and the SNR verdict halves the quant block; banks
-# POLICY_<platform>.json + a provenance-commented DEVICE_RULES row)
+# the policy-plane suite: verdict bus, statically pre-verified action
+# space, fleet-consistent vote, audited observe->decide->act
 policy-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_policy.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --selfdrive
 
-# the serving-fleet gate: KV-page migration round-trip + router +
-# hot_replica sentry suite, then the end-to-end probe (one Poisson
-# stream through colocated tp=8 vs prefill/decode-split tp=4 replicas
-# at the SAME 8 chips; exits nonzero unless the split beats colocated
-# on p99 ITL with IDENTICAL token streams, every migration within the
-# reshard peak bound and fleet-wide conservation closed; banks
-# FLEET_<platform>.json)
+# the serving-fleet suite: KV-page migration round-trip, router,
+# hot_replica sentry
 fleet-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --fleet
 
-# the request-plane gate: span-tree stitching + conservation + exemplar
-# reservoir + SLO-judge suite, then the end-to-end probe (a chaos-
-# delayed migration lane and a slowed prefill replica on the same
-# 8-chip disaggregated fleet; exits nonzero unless each degradation is
-# attributed to its true stage at p99, every sampled request's stage
-# sum matches e2e within clock confidence on the merged timeline, and
-# each breach episode lands exactly one slo_breach verdict answered by
-# one audited decide:fleet_route; banks REQUESTS_<platform>.json)
+# the request-plane suite: span-tree stitching, conservation, exemplar
+# reservoir, SLO judge
 request-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_requests.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --slo
 
-# the history tier: the fleet-lifetime run ledger + deterministic
-# changepoint kernel suite, then the end-to-end probe (a 12-run
-# synthetic trajectory with an injected -20% step and -2%/run drift
-# the detector must attribute to exactly those two (metric, run_id)
-# changepoints with zero false positives, the history_regression
-# verdict answered by one audited decide:policy, and the episode
-# re-armed after a recovered run; banks HISTORY_<platform>.json)
+# the history suite: the run ledger and the deterministic changepoint
+# kernel
 history-tests:
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_history.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --history
 
-# the static-analysis tier: jaxpr collective extraction + SPMD checks
-# + comm-lint + DEVICE_RULES validator suite, then the end-to-end probe
-# (extracts the flagship train step's and a reshard plan's collective
-# programs on the 8-dev mesh and exits nonzero unless the static wire
-# prediction equals the runtime traffic attribution byte-for-byte;
-# banks ANALYZE_<platform>.json) — plus the lint gate itself
+# the static-analysis suite: jaxpr collective extraction, SPMD checks,
+# comm-lint, DEVICE_RULES validator — plus the lint gate itself
 analysis-tests: comm-lint
 	env JAX_PLATFORMS=cpu python -m pytest tests/test_analysis.py -q \
 	  -p no:cacheprovider -p no:randomly
-	env JAX_PLATFORMS=cpu python bench.py --analyze
 
 # repo-invariant comm-lint (rules CL001-CL008, justified waivers only)
 # plus the DEVICE_RULES grammar validator; nonzero on any unwaived
@@ -249,14 +135,6 @@ analysis-tests: comm-lint
 comm-lint:
 	python -m ompi_tpu.analysis.lint ompi_tpu
 	python -m ompi_tpu.analysis.rules DEVICE_RULES.txt
-
-# regression gate over the banked trajectory artifact: non-zero exit
-# names every phase whose busbw/goodput/MFU column lost >10% (run it
-# with OLD= NEW= to diff two arbitrary banked artifacts)
-OLD ?= BENCH_r06.json
-NEW ?= BENCH_r06.json
-bench-compare:
-	python bench.py --compare $(OLD) $(NEW)
 
 # the comm/compute overlap tier: bucketed grad sync + collective-matmul
 # rings, INCLUDING the multi-device tests marked slow (excluded from

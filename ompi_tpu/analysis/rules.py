@@ -38,7 +38,7 @@ MODES = ("native", "staged", "quant", "bidir", "hier", "hier+quant")
 PLANES = ("ici", "dcn")
 
 # provenance headers emitted by machine rule-writers (coll_tune
-# --device / --from-ledger, bench.py --selfdrive's policy plane): a
+# --device / --from-ledger, the policy plane): a
 # '# learned from ...' comment is a machine-written claim about where
 # the rows came from, so its shape is part of the file contract
 _PROVENANCE_PREFIX = "# learned from "

@@ -26,8 +26,8 @@ nbc), so the stock dispatch is unchanged; raise ``coll_adapt_priority``
 to let its ibcast/ireduce win selection, or call
 ``ibcast_adapt``/``ireduce_adapt`` directly.
 
-Status (round-4 measurement, BASELINE.md "coll/adapt on the DCN
-stand-in"): on every fabric this box can express — shm+CMA, and
+Status (round-4 measurement, BASELINE.md "Adaptive collectives", the
+DCN stand-in): on every fabric this box can express — shm+CMA, and
 tcp-only 4-rank (the DCN stand-in) at 1/4/16 MB — whole-message
 binomial beats adapt by ~1.2-1.6×, because event-driven overlap needs
 CONCURRENT cores and this host has one: segment completion callbacks

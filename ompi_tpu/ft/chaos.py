@@ -18,8 +18,8 @@ run either reproduces a failure bit-for-bit or doesn't inject at all
                       (delivery reaches all survivors if any survivor
                       delivers).
 
-Every injection appends an attribution record to ``log`` so tests and
-the bench probe can assert exactly what fired where.
+Every injection appends an attribution record to ``log`` so tests can
+assert exactly what fired where.
 """
 
 from __future__ import annotations

@@ -75,7 +75,7 @@ def pvar_value(name: str) -> float:
 
 
 def report() -> Dict[str, Any]:
-    """Structured snapshot for comm_doctor --ft / the bench probe: the
+    """Structured snapshot for comm_doctor --ft: the
     recovery timeline records plus the shadow/recovery counters."""
     with _lock:
         return {"counters": dict(_counts),

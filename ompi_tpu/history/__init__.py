@@ -7,7 +7,7 @@ Three coupled pieces:
 * ``store``       — append-only, schema-versioned run ledger
   (``BENCH_HISTORY.jsonl`` + per-run downsampled step-series chunks)
   keyed by (run_id, platform, probe, metric).  ``run_id`` is supplied
-  by the caller — bench derives it from ledger content
+  by the caller — derived from ledger content
   (``store.next_run_id``); the plane itself never reads a wall clock.
 * ``changepoint`` — deterministic Page-Hinkley/CUSUM kernel over
   MAD-normalized residuals with min-run-count and sustain gates;
@@ -77,7 +77,7 @@ def _on_enabled_var(v: Any) -> None:
 _var.watch("history_enabled", _on_enabled_var)
 
 
-# ---- the bench-probe write path --------------------------------------
+# ---- the write path --------------------------------------------------
 
 def record_run(run_id: int, platform: str, probe: str, metric: str,
                value: float, unit: str = "",
@@ -110,13 +110,12 @@ def scan(platform: Optional[str] = None) -> List[Dict[str, Any]]:
     return sentry.scan(store, platform)
 
 
-# ---- the bench artifact schema ---------------------------------------
+# ---- the banked artifact schema --------------------------------------
 
-# one entry per wired bench probe: (banked artifact stem, dotted paths
-# of the extra headline gauges recorded beside the doc's own
-# metric/value row).  The SAME map drives the live probe append in
-# bench.py and the tools/history_backfill.py one-shot, so the two can
-# never disagree about what a probe's trajectory contains.
+# one entry per banked probe artifact: (artifact stem, dotted paths of
+# the extra headline gauges recorded beside the doc's own metric/value
+# row).  tools/history_backfill.py reads it to turn a directory of
+# ``<STEM>_<platform>.json`` artifacts into ledger rows.
 PROBE_GAUGES: Dict[str, Any] = {
     "goodput":   ("GOODPUT", ("mfu_pct", "overlap_efficiency")),
     "traffic":   ("TRAFFIC", ("hot_edge.ratio", "planes.ici")),

@@ -141,7 +141,7 @@ class HistorySentry:
 
     def rearm(self, platform: str, probe: str, metric: str) -> int:
         """Forget published episodes for one gauge — the explicit
-        re-arm hook tests and the bench probe use to model 'episode
+        re-arm hook tests use to model 'episode
         over after a recovered run' across repeated scans."""
         with self._lock:
             drop = [k for k in self._published

@@ -1,7 +1,7 @@
 """Append-only, schema-versioned run ledger for the history plane.
 
 One row per (run_id, platform, probe, metric): the headline gauge a
-bench probe banked for that run — goodput/MFU, per-plane busbw and
+probe banked for that run — goodput/MFU, per-plane busbw and
 bytes, serve tokens/s + ITL quantiles, spec-decode acceptance, quant
 SNR dB, ft time-to-recover, verdict/decision counts.  Rows optionally
 carry a deterministically downsampled ``series`` chunk (per-step
@@ -102,7 +102,7 @@ class HistoryStore:
 
     def next_run_id(self, platform: str, probe: str) -> int:
         """1 + the highest banked run_id for (platform, probe) — the
-        caller-supplied id bench uses; pure ledger content, no clock."""
+        caller-supplied id; pure ledger content, no clock."""
         with self._lock:
             ids = [k[0] for k in self._rows
                    if k[1] == platform and k[2] == probe]
@@ -180,7 +180,8 @@ class HistoryStore:
 
     def save_jsonl(self, path: str) -> int:
         """Rewrite the full ledger atomically (tmp + os.replace) —
-        used by the backfill tool; bench appends via append_jsonl."""
+        used by the backfill tool; live writers append via
+        append_jsonl."""
         rows = self.rows()
         tmp = path + ".tmp"
         with open(tmp, "w") as fh:
@@ -191,6 +192,6 @@ class HistoryStore:
 
 
 def append_jsonl(path: str, row: Dict[str, Any]) -> None:
-    """Append one row to the on-disk ledger (the bench-probe path)."""
+    """Append one row to the on-disk ledger (the live write path)."""
     with open(path, "a") as fh:
         fh.write(json.dumps(row, sort_keys=True) + "\n")

@@ -830,8 +830,8 @@ def make_train_step(cfg: Config, mesh: Optional[Mesh] = None,
             return dispatch(params, opt_state, tokens)
         # goodput/MFU ledger: blocked wall per step. Only wall + token
         # FLOPs are measurable from one blocked call — the comm split
-        # (exposed vs total) comes from the bench goodput probe's
-        # unsynced-floor methodology, never fabricated here.
+        # (exposed vs total) needs a device trace, never fabricated
+        # here.
         t0 = time.perf_counter()
         out = dispatch(params, opt_state, tokens)
         jax.block_until_ready(out)

@@ -306,7 +306,7 @@ def pvar_value(name: str) -> float:
 
 
 def report() -> Dict[str, Any]:
-    """Structured snapshot for comm_doctor --moe / the bench probe."""
+    """Structured snapshot for comm_doctor --moe."""
     with _lock:
         return {
             "steps": _steps,

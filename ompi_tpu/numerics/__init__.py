@@ -106,8 +106,8 @@ _tls = threading.local()      # in-flight probe entry (note_arm target)
 
 
 def begin_step(step: int) -> None:
-    """Set the step index verdicts attribute to (training loops and
-    the bench probe call this; record_step advances it otherwise)."""
+    """Set the step index verdicts attribute to (training loops
+    call this; record_step advances it otherwise)."""
     global _cur_step
     _cur_step = int(step)
 
@@ -317,8 +317,7 @@ def pvar_value(name: str) -> float:
 
 
 def report() -> Dict[str, Any]:
-    """Structured snapshot for comm_doctor --numerics / the bench
-    probe."""
+    """Structured snapshot for comm_doctor --numerics."""
     with _lock:
         steps = [dict(r) for r in _steps]
         div = [dict(v) for v in _div_verdicts]

@@ -74,8 +74,8 @@ def audit(ctx, step: int, buckets: Sequence[Dict[str, Any]],
     Returns ``{step, rank, compared, divergent: [...], missing,
     first}`` where each divergent row is ``{step, bucket, rank,
     digest, majority_digest}`` and ``first`` is the first divergent
-    (step, bucket, rank) triple — the attribution the bench probe and
-    the doctor arm assert on.  ``divergent`` is ordered by bucket, so
+    (step, bucket, rank) triple — the attribution the doctor arm
+    asserts on.  ``divergent`` is ordered by bucket, so
     ``first`` names the earliest corrupted bucket."""
     publish(ctx, step, buckets)
     peers = list(peers if peers is not None else range(ctx.size))

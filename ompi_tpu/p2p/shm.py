@@ -35,7 +35,7 @@ from . import wire
 _var.register("transport", "shm", "ring_size", 1 << 22, type=int, level=4,
               help="Bytes per directed shared-memory ring channel. 4 MiB "
                    "default: the fragment path then moves 1 MiB chunks "
-                   "with few drain handoffs (bandwidth sweep, BASELINE.md).")
+                   "with few drain handoffs (BASELINE.md, host p2p path).")
 
 
 def _host_key() -> str:

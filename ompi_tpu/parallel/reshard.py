@@ -1183,7 +1183,7 @@ def pvar_value(name: str) -> float:
 
 
 def report() -> Dict[str, Any]:
-    """Structured snapshot for comm_doctor --reshard / the bench probe:
+    """Structured snapshot for comm_doctor --reshard:
     the compiled-plan cache view and the last executed plan's per-step
     audit."""
     with _lock:

@@ -15,8 +15,8 @@ no contention — because its only job is to order arms the way a real
 two-tier fabric would: flat arms pay for their full cross-boundary
 share, `hier` pays only for the scattered outer stage, `hier+quant` for
 a quarter of that.  The shim sits in coll/xla's audit path (one branch
-when disabled) so `bench.py --pod`, `coll_tune --device` hier sweeps and
-the plane-keyed perf-ledger cells all see the same skew.
+when disabled) so `coll_tune --device` hier sweeps and the plane-keyed
+perf-ledger cells all see the same skew.
 """
 
 from __future__ import annotations

@@ -20,7 +20,7 @@ Sample sources:
    (``note_arm``) — only arm-annotated samples fold, so host-path and
    barrier dispatches never pollute the model. Device dispatch is
    async: a native sample measures dispatch latency unless the caller
-   blocks — the bench probes and the staged arm (which blocks on D2H)
+   blocks — blocking callers and the staged arm (which blocks on D2H)
    provide the grounded timings; docs cover the caveat.
 2. ``note_sample``: an already-measured dispatch from outside the
    wrapper (the reshard executor's plan steps, the fused decode rings).
@@ -70,7 +70,7 @@ _var.register("perf", "model", "alpha", 0.2, type=float, level=4,
 _var.register("perf", "", "peak_tflops", 0.0, type=float, level=3,
               help="Accelerator peak TFLOP/s for MFU accounting in the "
                    "flagship step wrapper (0: unknown -> mfu "
-                   "unmeasured; bench probes pass their own peak).")
+                   "unmeasured).")
 
 enabled: bool = bool(_var.get("perf_enabled", False))
 

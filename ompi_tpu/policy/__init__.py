@@ -18,7 +18,7 @@ Plane conventions (same bar as trace/health/perf/traffic/moe):
   loop runs whenever *moe* is enabled, policy plane on or off.
 * ``PVARS`` read through ``spc.get``/``snapshot`` -> MPI_T ->
   Prometheus, zero new transport.
-* ``report()``/``reset()`` for the doctor and the bench probes.
+* ``report()``/``reset()`` for the doctor.
 """
 
 from __future__ import annotations
@@ -138,7 +138,7 @@ def pvar_value(name: str) -> float:
 
 
 def report() -> Dict[str, Any]:
-    """Structured snapshot for comm_doctor --policy / the bench probe:
+    """Structured snapshot for comm_doctor --policy:
     the decision ledger plus the attribution figure (share of applied
     adaptations naming their causing verdict — the acceptance bar is
     100, i.e. zero unattributed decisions)."""

@@ -246,8 +246,8 @@ def note_spec(drafted: int, accepted: int) -> None:
     """One speculative verify window: ``drafted`` tokens proposed by the
     draft source, ``accepted`` of them matched the target model's greedy
     choice (0 ≤ accepted ≤ drafted).  The MEASURED acceptance rate —
-    accepted/drafted over the run — is the number bench banks; it is
-    never assumed."""
+    accepted/drafted over the run — is the number the plane reports;
+    it is never assumed."""
     global _spec_drafted, _spec_accepted, _spec_windows
     with _lock:
         _spec_drafted += int(drafted)
@@ -355,7 +355,7 @@ def fleet_pvar_value(name: str) -> float:
 
 
 def fleet_report() -> Dict[str, Any]:
-    """Structured fleet state for comm_doctor --fleet / bench --fleet."""
+    """Structured fleet state for comm_doctor --fleet."""
     with _lock:
         rows = [dict(_fleet_rows[r]) for r in sorted(_fleet_rows)]
         for row in rows:
@@ -395,7 +395,7 @@ def _percentile(sorted_vals: List[float], q: float) -> float:
 
 
 def report() -> Dict[str, Any]:
-    """Structured plane state for comm_doctor --serve / bench --serve."""
+    """Structured plane state for comm_doctor --serve."""
     with _lock:
         itl = sorted(_itl)
         total = _prefill_s + _decode_s + _host_s

@@ -14,7 +14,7 @@ Two topologies over the same chips:
   continuous-batching loop, prefill and decode serialized on the same
   engine: a long prompt's prefill-bucket call blocks every in-flight
   sequence on that replica for its full duration (the head-of-line ITL
-  spike the bench measures).
+  spike the fleet's p99 ITL shows).
 * **disaggregated** — the first ``prefill_replicas`` replicas ONLY
   prefill; the rest ONLY decode.  A finished prompt's KV pages migrate
   prefill→decode through :func:`ServingFleet.migrate`: a KV-page
@@ -40,7 +40,7 @@ uses, with one clock per replica on a common global axis: the prefill
 replica works ahead on its own timeline, and a migrated sequence joins
 the decode batch only once the decode clock reaches the handoff time —
 so the decode loop NEVER idles through a prefill, which is exactly the
-p99-ITL win the bench gates on.  Prefill capacity is modeled per
+p99-ITL win of the split.  Prefill capacity is modeled per
 prefill↔decode pairing (decode replica i prefills on prefill replica
 ``i % n_prefill``'s lane).
 
@@ -182,13 +182,6 @@ class _DisaggScheduler(_ReplicaScheduler):
             first, _ = pre.engine.prefill(pslot, req.prompt,
                                           rid=req.rid)
             pdur = time.perf_counter() - t0
-            # bench --slo fault injection: a slowed prefill replica is
-            # a multiplier on the VIRTUAL prefill duration, so the lane
-            # clock, the goodput split and the request plane's prefill
-            # stage all degrade consistently
-            scale = float(_var.get("serve_req_chaos_prefill_scale", 1.0))
-            if scale != 1.0:
-                pdur *= max(scale, 0.0)
             pre.clock += pdur
             pre.prefills += 1
             pre.prefill_s += pdur
@@ -220,10 +213,6 @@ class _DisaggScheduler(_ReplicaScheduler):
                                        len(req.prompt), req.max_new,
                                        rid=req.rid)
             mdur = time.perf_counter() - t0
-            # bench --slo fault injection: a degraded migration lane is
-            # extra virtual delay on every KV hand-off hop
-            mdur += 1e-3 * float(_var.get("serve_req_chaos_migrate_ms",
-                                          0.0))
             pre.clock += mdur
             pcache.release(pslot)
             if _requests.enabled:

@@ -65,15 +65,6 @@ _var.register("serve", "req", "slo_itl_ms", 0.0, type=float, level=3,
 _var.register("serve", "req", "slo_e2e_ms", 0.0, type=float, level=3,
               help="End-to-end (arrival to finish) SLO target in ms "
                    "(0 disables).")
-_var.register("serve", "req", "chaos_migrate_ms", 0.0, type=float, level=4,
-              help="Fault injection for bench.py --slo: extra virtual "
-                   "delay (ms) added to every KV-page migration hop, "
-                   "modelling a degraded DCN lane. 0 = off.")
-_var.register("serve", "req", "chaos_prefill_scale", 1.0, type=float,
-              level=4,
-              help="Fault injection for bench.py --slo: multiplier on "
-                   "every fleet prefill's virtual duration, modelling "
-                   "a slowed prefill replica. 1.0 = off.")
 
 enabled: bool = bool(_var.get("serve_req_enabled", False))
 
@@ -409,7 +400,7 @@ def prometheus_rows(rank: int = 0, comm: str = "world",
 
 
 def report() -> Dict[str, Any]:
-    """Structured plane state for comm_doctor --requests / bench --slo."""
+    """Structured plane state for comm_doctor --requests."""
     with _lock:
         e2e = sorted(_e2e)
         stage_rows = {}

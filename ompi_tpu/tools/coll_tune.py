@@ -656,7 +656,7 @@ def main(argv=None) -> int:
                 not in os.environ.get("XLA_FLAGS", ""):
             # a 1-device cpu sweep would emit degenerate rules (native
             # arms become no-ops over a size-1 axis) — force the 8-way
-            # virtual mesh exactly as bench.py does
+            # virtual mesh the CPU test suite runs on
             os.environ["XLA_FLAGS"] = (
                 os.environ.get("XLA_FLAGS", "")
                 + " --xla_force_host_platform_device_count=8").strip()

@@ -244,8 +244,9 @@ def build_traffic_report(
     """(human text, structured dict) for the topology traffic plane: the
     per-edge byte matrix as an ASCII heatmap (meshes up to 16 devices),
     the hottest edges, the ICI/DCN/host per-plane rollup, and the
-    hot-link sentry verdicts. ``path`` loads a banked TRAFFIC json
-    (bench.py --traffic); default reads the live in-process plane."""
+    hot-link sentry verdicts. ``path`` loads a TRAFFIC json (the
+    plane's report, bare or under ``"traffic"``); default reads the
+    live in-process plane."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -341,8 +342,9 @@ def build_numerics_report(
     counts, non-finite origin verdicts (the first rank/step/op that
     produced each NaN/Inf episode), quant-SNR state vs the banked
     baseline, divergence-auditor verdicts and the per-step grad-norm /
-    loss telemetry tail. ``path`` loads a banked NUMERICS json
-    (bench.py --numerics); default reads the live in-process plane."""
+    loss telemetry tail. ``path`` loads a NUMERICS json (the plane's
+    report, bare or under ``"report"``); default reads the live
+    in-process plane."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -404,8 +406,8 @@ def build_reshard_report(
     plan/step/byte counters, the compiled-plan cache (op sequence, wire
     bytes, peak-vs-bound accounting, device_put fallback reasons) and
     the last executed plan's per-step decision audit. ``path`` loads a
-    banked RESHARD json (bench.py --reshard); default reads the live
-    in-process engine."""
+    RESHARD json (the engine's report, bare or under ``"report"``);
+    default reads the live in-process engine."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -454,14 +456,15 @@ def build_analyze_report(
         path: Optional[str] = None) -> Tuple[str, Dict[str, Any]]:
     """(human text, structured dict) for the static communication
     verifier: per-program static-vs-runtime wire rows and SPMD check
-    issues from a banked ANALYZE json (bench.py --analyze).  The
-    verifier has no live in-process state (it runs whole programs),
-    so the default picks the newest banked artifact."""
+    issues from an ANALYZE json (``value`` the verdict, ``programs``
+    the per-program rows).  The verifier has no live in-process state
+    (it runs whole programs), so the default picks the newest
+    ``ANALYZE_*.json`` in the working directory."""
     if not path:
         hits = sorted(glob.glob("ANALYZE_*.json"))
         if not hits:
-            return ("static verifier: no ANALYZE_*.json banked yet "
-                    "(run bench.py --analyze)"), {}
+            return ("static verifier: no ANALYZE_*.json in the "
+                    "working directory (pass --analyze PATH)"), {}
         path = hits[-1]
     with open(path) as fh:
         doc = json.load(fh)
@@ -495,9 +498,9 @@ def build_ft_report(
     """(human text, structured dict) for the elastic-recovery plane:
     recovery/steps-lost/shadow-refresh counters and, per recovery, the
     full choreography timeline (trip verdict -> shrink epoch -> reshard
-    plan -> resume step) with wall-clock milestones.  ``path`` loads a
-    banked ELASTIC json (bench.py --elastic); default reads the live
-    in-process plane."""
+    plan -> resume step) with wall-clock milestones.  ``path`` loads an
+    ELASTIC json (the plane's report, bare or under ``"report"``);
+    default reads the live in-process plane."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -542,8 +545,8 @@ def build_moe_report(
     """(human text, structured dict) for the MoE routing plane: routed/
     dropped token counters, per-expert load table, live capacity/aux
     scaling, hot-expert verdicts and the adaptation timeline.  ``path``
-    loads a banked MOE json (bench.py --moe); default reads the live
-    in-process plane."""
+    loads a MOE json (the plane's report, bare or under ``"report"``);
+    default reads the live in-process plane."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -593,8 +596,9 @@ def build_serve_report(
     """(human text, structured dict) for the serving plane: continuous-
     batching occupancy, the prefill/decode/host goodput split, inter-
     token latency percentiles, the per-request lifecycle table and the
-    decode collective arm audit.  ``path`` loads a banked SERVE json
-    (bench.py --serve); default reads the live in-process plane."""
+    decode collective arm audit.  ``path`` loads a SERVE json (the
+    plane's report, bare or under ``"report"`` beside ``"decisions"``);
+    default reads the live in-process plane."""
     decisions: Dict[str, Any] = {}
     if path:
         with open(path) as fh:
@@ -677,8 +681,8 @@ def build_policy_report(
     """(human text, structured dict) for the policy plane: published
     verdicts, the registered (statically pre-verified) rule table, and
     the verdict->vote->action->effect ledger with its attribution
-    percentage.  ``path`` loads a banked POLICY json (bench.py
-    --selfdrive); default reads the live in-process plane."""
+    percentage.  ``path`` loads a POLICY json (the plane's report, bare
+    or under ``"report"``); default reads the live in-process plane."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -742,8 +746,9 @@ def build_fleet_report(
     """(human text, structured dict) for the serving fleet: per-replica
     occupancy/goodput/ITL rows, the KV-page migration ledger (wire
     bytes + standing under the reshard peak contract) and the router
-    decision table.  ``path`` loads a banked FLEET json (bench.py
-    --fleet); default reads the live in-process fleet ledger."""
+    decision table.  ``path`` loads a FLEET json (the fleet report,
+    bare or under ``"report"``); default reads the live in-process
+    fleet ledger."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -809,8 +814,9 @@ def build_requests_report(
     """(human text, structured dict) for the request plane: headline
     counters, the SLO judge targets, per-stage latency quantiles, the
     tail-attribution rollup and an ASCII waterfall of the slowest kept
-    exemplar.  ``path`` loads a banked REQUESTS json (bench.py --slo);
-    default reads the live in-process request ledger."""
+    exemplar.  ``path`` loads a REQUESTS json (the plane's report, bare
+    or under ``"report"``); default reads the live in-process request
+    ledger."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -903,8 +909,8 @@ def build_history_report(
     """(human text, structured dict) for the history plane: one
     sparkline + trend row per banked (probe, metric) trajectory and
     the changepoint verdicts the sentry attributed.  ``path`` loads a
-    banked HISTORY json (bench.py --history); default reads the live
-    in-process run ledger."""
+    HISTORY json (the plane's report, bare or under ``"report"``);
+    default reads the live in-process run ledger."""
     if path:
         with open(path) as fh:
             rep = json.load(fh)
@@ -998,7 +1004,7 @@ def _parse_args(argv: Optional[List[str]]) -> argparse.Namespace:
                     help="render the topology-traffic-plane section: "
                          "per-edge ASCII heatmap, ICI/DCN rollup, "
                          "hot-link verdicts. With a path, loads a "
-                         "banked TRAFFIC json (bench.py --traffic); "
+                         "TRAFFIC json (the plane's report); "
                          "bare flag reads the live in-process plane")
     ap.add_argument("--numerics", nargs="?", const="", default=None,
                     metavar="NUMERICS.json",
@@ -1006,22 +1012,22 @@ def _parse_args(argv: Optional[List[str]]) -> argparse.Namespace:
                          "origin verdicts (rank/step/op), quant-SNR "
                          "sentry state, divergence-auditor verdicts, "
                          "step telemetry. With a path, loads a banked "
-                         "NUMERICS json (bench.py --numerics); bare "
+                         "NUMERICS json (the plane's report); bare "
                          "flag reads the live in-process plane")
     ap.add_argument("--reshard", nargs="?", const="", default=None,
                     metavar="RESHARD.json",
                     help="render the redistribution-engine section: "
                          "plan cache (op sequences, wire/peak "
                          "accounting), last-plan per-step decision "
-                         "audit. With a path, loads a banked RESHARD "
-                         "json (bench.py --reshard); bare flag reads "
+                         "audit. With a path, loads a RESHARD json "
+                         "(the engine's report); bare flag reads "
                          "the live in-process engine")
     ap.add_argument("--analyze", nargs="?", const="", default=None,
                     metavar="ANALYZE.json",
                     help="render the static-verifier section: "
                          "per-program static-vs-runtime wire rows and "
-                         "SPMD check issues from a banked ANALYZE "
-                         "json (bench.py --analyze); bare flag picks "
+                         "SPMD check issues from an ANALYZE json; "
+                         "bare flag picks "
                          "the newest ANALYZE_*.json")
     ap.add_argument("--ft", nargs="?", const="", default=None,
                     metavar="ELASTIC.json",
@@ -1029,15 +1035,15 @@ def _parse_args(argv: Optional[List[str]]) -> argparse.Namespace:
                          "trip -> shrink -> reshard -> resume timeline "
                          "per survived rank death, counters, shadow "
                          "refreshes. With a path, loads a banked "
-                         "ELASTIC json (bench.py --elastic); bare "
+                         "ELASTIC json (the plane's report); bare "
                          "flag reads the live in-process plane")
     ap.add_argument("--moe", nargs="?", const="", default=None,
                     metavar="MOE.json",
                     help="render the MoE routing-plane section: routing "
                          "table, per-expert load, hot-expert verdicts, "
                          "capacity/aux adaptation timeline. With a "
-                         "path, loads a banked MOE json (bench.py "
-                         "--moe); bare flag reads the live in-process "
+                         "path, loads a MOE json (the plane's "
+                         "report); bare flag reads the live in-process "
                          "plane")
     ap.add_argument("--serve", nargs="?", const="", default=None,
                     metavar="SERVE.json",
@@ -1045,8 +1051,8 @@ def _parse_args(argv: Optional[List[str]]) -> argparse.Namespace:
                          "batching occupancy, goodput split, inter-"
                          "token latency p50/p99, per-request lifecycle "
                          "table and the decode_ag/decode_rs arm audit. "
-                         "With a path, loads a banked SERVE json "
-                         "(bench.py --serve); bare flag reads the live "
+                         "With a path, loads a SERVE json (the "
+                         "plane's report); bare flag reads the live "
                          "in-process plane")
     ap.add_argument("--policy", nargs="?", const="", default=None,
                     metavar="POLICY.json",
@@ -1054,30 +1060,30 @@ def _parse_args(argv: Optional[List[str]]) -> argparse.Namespace:
                          "verdicts, the pre-verified rule table and "
                          "the verdict->vote->action->effect ledger "
                          "with attribution. With a path, loads a "
-                         "banked POLICY json (bench.py --selfdrive); "
+                         "POLICY json (the plane's report); "
                          "bare flag reads the live in-process plane")
     ap.add_argument("--fleet", nargs="?", const="", default=None,
                     metavar="FLEET.json",
                     help="render the serving-fleet section: per-replica "
                          "occupancy/goodput/ITL rows, the KV-page "
                          "migration ledger and the router decision "
-                         "table. With a path, loads a banked FLEET "
-                         "json (bench.py --fleet); bare flag reads "
+                         "table. With a path, loads a FLEET json "
+                         "(the fleet report); bare flag reads "
                          "the live in-process fleet ledger")
     ap.add_argument("--requests", nargs="?", const="", default=None,
                     metavar="REQUESTS.json",
                     help="render the request-plane section: per-request "
                          "stage waterfall, tail-attribution rollup and "
                          "the SLO judge counters. With a path, loads a "
-                         "banked REQUESTS json (bench.py --slo); bare "
+                         "REQUESTS json (the plane's report); bare "
                          "flag reads the live in-process request ledger")
     ap.add_argument("--history", nargs="?", const="", default=None,
                     metavar="HISTORY.json",
                     help="render the history-plane section: one "
                          "sparkline/trend row per banked run "
                          "trajectory plus the changepoint verdicts. "
-                         "With a path, loads a banked HISTORY json "
-                         "(bench.py --history); bare flag reads the "
+                         "With a path, loads a HISTORY json (the "
+                         "plane's report); bare flag reads the "
                          "live in-process run ledger")
     ap.add_argument("--live", action="store_true",
                     help="gather over comm_world instead of reading "

@@ -1,11 +1,10 @@
 """history_backfill — seed BENCH_HISTORY.jsonl from banked artifacts.
 
-One-shot: walks a directory of already-banked bench artifacts
+One-shot: walks a directory of already-banked probe artifacts
 (``GOODPUT_<platform>.json``, ``SERVE_<platform>.json``, ...) and
 appends one history-plane run per (platform, probe) artifact, so the
 trajectory is non-empty from day one.  The probe -> headline-gauge map
-is ``ompi_tpu.history.PROBE_GAUGES`` — the same one the live bench
-append uses, so backfilled and live rows can never disagree.
+is ``ompi_tpu.history.PROBE_GAUGES``.
 
 Idempotent against an existing ledger: an artifact whose gauges
 already match the newest banked run for its (platform, probe) is
@@ -84,7 +83,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="history_backfill",
         description="Seed the history plane's BENCH_HISTORY.jsonl from "
-                    "already-banked bench artifacts (one run per "
+                    "already-banked probe artifacts (one run per "
                     "artifact; idempotent).")
     ap.add_argument("--root", default=".",
                     help="directory holding the banked *_<platform>"

@@ -23,7 +23,7 @@ and log-bucketed histograms, never a lone mean.
     ineligible: reasons outrank rules by design) is drift — the rules
     file no longer matches what the fleet executes.
   * ``analyze``         — the whole report as one dict (the doctor CLI
-    and ``bench.py --doctor`` render it).
+    renders it).
 """
 
 from __future__ import annotations

@@ -20,7 +20,7 @@ conservation check meaningful:
   excess over the population median — "why is THIS request's tail
   bad", answered by the system.
 * :func:`analyze_requests` — the combined report comm_doctor
-  --requests renders and bench --slo gates on.
+  --requests renders.
 """
 
 from __future__ import annotations

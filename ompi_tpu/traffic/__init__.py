@@ -339,7 +339,7 @@ def pvar_value(name: str) -> float:
 
 
 def report() -> Dict[str, Any]:
-    """Structured snapshot for comm_doctor --traffic / the bench probe."""
+    """Structured snapshot for comm_doctor --traffic."""
     doc = matrix.to_json()
     doc["hotlink_trips"] = sentry.trips()
     doc["verdicts"] = sentry.verdicts()

@@ -543,7 +543,7 @@ def test_load_chrome_partial_offsets_roundtrip(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_comm_doctor_policy_banked_json_golden(tmp_path, capsys):
-    """--policy with a banked POLICY json (bench.py --selfdrive shape)
+    """--policy with a banked POLICY json (a report under "report")
     renders standalone and round-trips the report verbatim into the
     structured output, under the v11 schema pin."""
     report = {

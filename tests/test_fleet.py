@@ -265,7 +265,7 @@ def test_comm_doctor_fleet_live_section(capsys):
 
 
 def test_comm_doctor_fleet_banked_json_golden(tmp_path, capsys):
-    """--fleet with a banked FLEET json (bench.py --fleet shape)
+    """--fleet with a banked FLEET json (a report under "report")
     renders standalone and round-trips the report verbatim into the
     structured output, under the v12 schema pin."""
     report = {

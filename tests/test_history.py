@@ -10,16 +10,13 @@ positives), the HistorySentry (idempotent scans, CL007 verdict
 envelope, bad-direction filtering, within-run series drift, policy-bus
 integration driving exactly one audited decide:policy), the pvar
 read-through under the Prometheus grammar, comm_doctor --history
-(live + banked golden under the v14 schema), the backfill tool's
-idempotency, and bench.py --compare --against-history as a subprocess
-gate.
+(live + banked golden under the v14 schema), and the backfill tool's
+idempotency.
 """
 
 import json
 import os
 import re
-import subprocess
-import sys
 
 import pytest
 
@@ -68,7 +65,7 @@ def plane():
 
 
 def _hist_lcg(seed):
-    """The bench probe's deterministic noise source, verbatim."""
+    """A deterministic noise source (a 31-bit LCG)."""
     s = (int(seed) * 2654435761) & 0x7FFFFFFF
     while True:
         s = (1103515245 * s + 12345) & 0x7FFFFFFF
@@ -143,7 +140,7 @@ def test_store_jsonl_round_trip_tolerant(tmp_path):
     assert st2.trajectory("serve", "tok") == [(1, 220.0), (2, 200.0)]
     assert st2.series_of(1, "cpu", "serve", "tok") == [1.0, 2.0, 3.0]
     assert st2.rows()[0]["note"] == "x"
-    # append_jsonl is the live bench path
+    # append_jsonl is the live write path
     append_jsonl(path, st.record(3, "cpu", "serve", "tok", 210.0))
     st3 = HistoryStore()
     st3.load_jsonl(path)
@@ -171,8 +168,8 @@ def test_kernel_drift_onset_mid_ramp():
     vals = [1.8 * (1.0 - 0.02 * i) for i in range(12)]
     cps = detect(vals)
     assert [c["direction"] for c in cps] == ["down"]
-    # half-max onset rule lands mid-ramp at index 6 (run_id 7 in the
-    # probe's 1-based ledger) — pinned, see bench.py DRIFT_ONSET
+    # half-max onset rule lands mid-ramp at index 6 (run_id 7 in a
+    # 1-based ledger) — pinned
     assert cps[0]["index"] == 6
     assert cps[0]["magnitude"] < 0.0
 
@@ -410,7 +407,7 @@ def test_enable_rehydrates_ledger(tmp_path, plane):
 
 
 # ---------------------------------------------------------------------------
-# headline rows: the probe -> gauge map bench and backfill share
+# headline rows: the probe -> gauge map backfill reads
 # ---------------------------------------------------------------------------
 
 def test_headline_rows_doc_metric_plus_extras():
@@ -605,52 +602,3 @@ def test_backfill_dry_run_writes_nothing(tmp_path, capsys):
     assert rc == 0
     capsys.readouterr()
     assert not os.path.exists(out)
-
-
-# ---------------------------------------------------------------------------
-# bench.py --compare --against-history: the trajectory gate
-# ---------------------------------------------------------------------------
-
-def _run_against_history(root, new, ledger, window=5):
-    return subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py"), "--compare",
-         str(new), "--against-history", str(ledger),
-         "--history-window", str(window)],
-        capture_output=True, text=True, cwd=root, timeout=120)
-
-
-def test_bench_against_history_cli(tmp_path):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    ledger = tmp_path / "BENCH_HISTORY.jsonl"
-    st = HistoryStore()
-    for i in range(5):
-        append_jsonl(str(ledger), st.record(
-            i + 1, "cpu", "goodput", "goodput_pct", 80.0 + i * 0.1))
-    new = tmp_path / "GOODPUT_new.json"
-    new.write_text(json.dumps({"metric": "goodput_pct", "value": 80.0,
-                               "unit": "%", "platform": "cpu"}))
-    r = _run_against_history(root, new, ledger)
-    assert r.returncode == 0, r.stdout + r.stderr
-    doc = json.loads(r.stdout.strip().splitlines()[-1])
-    assert doc["metric"] == "bench_compare_history"
-    assert doc["probe"] == "goodput" and doc["regressions"] == []
-
-    # -25% vs the trajectory median: gate trips, names metric + run_id
-    new.write_text(json.dumps({"metric": "goodput_pct", "value": 60.0,
-                               "unit": "%", "platform": "cpu"}))
-    r = _run_against_history(root, new, ledger)
-    assert r.returncode != 0
-    blame = r.stdout + r.stderr
-    assert "goodput/goodput_pct" in blame
-    assert "first regressed run_id 6" in blame
-
-
-def test_bench_against_history_no_trajectory(tmp_path):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    ledger = tmp_path / "BENCH_HISTORY.jsonl"
-    ledger.write_text("")
-    new = tmp_path / "X.json"
-    new.write_text(json.dumps({"metric": "goodput_pct", "value": 1.0}))
-    r = _run_against_history(root, new, ledger)
-    assert r.returncode != 0
-    assert "no history rows" in (r.stdout + r.stderr)

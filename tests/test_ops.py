@@ -2,7 +2,7 @@
 
 Interpret mode executes the same kernel logic the TPU backend compiles, so
 these validate the online-softmax state machine and the ring matmul
-schedules; the real-chip numbers come from bench.py.
+schedules; the real-chip numbers come from the benchmark's cells.
 """
 
 import jax

@@ -46,8 +46,6 @@ def _clean_state():
     for name in ("policy_enabled", "serve_req_enabled",
                  "serve_req_exemplar_k", "serve_req_slo_ttft_ms",
                  "serve_req_slo_itl_ms", "serve_req_slo_e2e_ms",
-                 "serve_req_chaos_migrate_ms",
-                 "serve_req_chaos_prefill_scale",
                  "topo_sim_dcn_axes", "topo_sim_dcn_us_per_mib"):
         var.registry.clear_cli(name)
     var.registry.reset_cache()
@@ -75,7 +73,7 @@ def _stream(n=6, seed=7, max_new=(3, 5)):
 
 def _merge_rings(tmp_path, offsets=None, best_rtt=None):
     """Round-trip this process's per-rank rings through the Chrome
-    format and merge them — the same path bench --slo gates on."""
+    format and merge them — the path comm_doctor --requests reads."""
     ranks = sorted({e["rank"] for e in trace.events()})
     paths = [trace.save_chrome(str(tmp_path / f"rank{r}.json"), rank=r)
              for r in ranks]

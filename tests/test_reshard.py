@@ -46,7 +46,7 @@ _VARS = ("traffic_enabled", "perf_enabled", "coll_xla_mode")
 @pytest.fixture
 def plane():
     """Clears engine/traffic/trace state around each test; set(...) routes
-    vars through the CLI layer exactly like the bench probe does."""
+    vars through the CLI layer exactly like a command-line option."""
     reset()
     traffic.reset()
     perf.reset()
