@@ -6,6 +6,8 @@ math where the data is" components are Pallas kernels:
 
   * ``attention`` — block flash attention (VMEM-resident online softmax),
     plus a partials variant that plugs into ring attention's merge step;
+  * ``paged_attention`` — decode attention that reads each row's live
+    pages of the serving tier's KV pools in place, by block table;
   * ``collective_matmul`` — latency-hiding allgather-matmul and
     matmul-reduce-scatter (comm/compute overlap on ICI), the TPU-native
     answer to the reference's segmented/pipelined collectives

@@ -4,7 +4,7 @@ The cache is the serving tier's only large mutable state: per layer one
 K and one V page pool in the canonical dim-0 layout
 ``(tp, n_pages, page_size, heads/tp, head_dim)`` — every device holds
 its own heads' slice of EVERY page, so a sequence's pages live on all
-devices at once and the paged-attention gather is purely local.
+devices at once and the paged attention reads them locally, in place.
 
 Page bookkeeping (free list, per-slot block tables, sequence lengths)
 is host-side integer state: admitting or evicting a sequence moves NO

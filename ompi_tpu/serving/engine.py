@@ -43,10 +43,11 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from .. import trace
-from ..models.transformer import (_rms_norm, decode_attention,
-                                  rope_rows)
+from ..models.transformer import _rms_norm, rope_rows
+from ..ops.paged_attention import paged_attention
 from ..parallel.ring import attention_reference
 from .cache import PagedKVCache
 
@@ -93,17 +94,27 @@ def _j_page_write(kp, vp, k_new, v_new, page_idx, offset):
     return kp, vp
 
 
-@jax.jit
-def _j_paged_attn(q, kp, vp, bt, q_pos):
-    """Decode attention against the paged pools: gather each slot's
-    pages by block table, flatten to key positions, run the shared
-    ``decode_attention`` core.  Heads are tp-sharded → fully local."""
-    k = jnp.take(kp, bt, axis=1)       # (tp, B, pmax, page, hl, hd)
-    v = jnp.take(vp, bt, axis=1)
-    r, b, pmax, pg, hl, hd = k.shape
-    k = k.reshape(r, b, pmax * pg, hl, hd)
-    v = v.reshape(r, b, pmax * pg, hl, hd)
-    att = decode_attention(q, k, v, q_pos)
+@partial(jax.jit, static_argnames=("mesh", "axis"))
+def _j_paged_attn(q, kp, vp, bt, q_pos, mesh=None, axis=None):
+    """Decode attention against the paged pools, read in place: the
+    ``ompi_paged_attn`` kernel (ops/paged_attention) reads each row's
+    pages up to its ``q_pos`` by block table and attends them with the
+    causal bound of ``decode_attention``; an inactive row (q_pos −1)
+    reads nothing and yields zeros.  Heads are tp-sharded → fully local:
+    at tp > 1 (``mesh``/``axis`` of the engine's comm) every device runs
+    the kernel on its own canonical row inside a shard_map, since GSPMD
+    cannot partition a Mosaic kernel."""
+    lengths = jnp.maximum(q_pos + 1, 0)
+    r, b, hl, hd = q.shape
+    if mesh is None:
+        att = paged_attention(q, kp, vp, bt, lengths)
+    else:
+        row = P(axis)
+        # comm-lint: disable=CL001 block-local paged kernel: no collective inside
+        att = jax.shard_map(paged_attention, mesh=mesh,
+                            in_specs=(row, row, row, P(), P()),
+                            out_specs=row, check_vma=False)(
+                                q, kp, vp, bt, lengths)
     return att.reshape(r, b, hl * hd)
 
 
@@ -366,6 +377,11 @@ class ServingEngine:
                                            "decode_rs": 0,
                                            "decode_collmm": 0}
         self.wire_bytes = 0
+        # key pages the decode attention read, and the pages of the block
+        # tables it was handed, over every decode step and window
+        self.attn_pages: Dict[str, int] = {"read": 0, "table": 0}
+        self._attn_comm = ({"mesh": dc.mesh, "axis": dc.axis}
+                           if dc.n > 1 else {})
         if self.fused:
             self._init_fused(params, cdt, can)
 
@@ -580,6 +596,19 @@ class ServingEngine:
         red = self._ag(self._rs(part))
         return _j_logits_argmax(red, b=b)
 
+    def _count_pages(self, positions: np.ndarray) -> Tuple[int, int]:
+        """(pages the decode attention reads, pages in its block tables)
+        for one step over the flat ``positions`` (−1 = inactive), added
+        to ``attn_pages``.  The kernel reads ⌈(q_pos+1)/page⌉ pages of a
+        row; the fused program gathers its whole table."""
+        table = int(positions.size) * self.cache.max_pages_per_seq
+        live = np.maximum(positions.astype(np.int64) + 1, 0)
+        read = table if self.fused else int(
+            (-(-live // self.cache.page_size)).sum())
+        self.attn_pages["read"] += read
+        self.attn_pages["table"] += table
+        return read, table
+
     @staticmethod
     def _bucket(n: int) -> int:
         p = 8
@@ -626,8 +655,9 @@ class ServingEngine:
     def decode_step(self, tokens: np.ndarray, positions: np.ndarray):
         """One continuous-batching decode step over the FULL device
         batch: ``tokens``/``positions`` are (max_seqs,) with
-        position −1 marking an inactive slot (its lane computes masked
-        garbage on the scratch page — the batch shape never changes, so
+        position −1 marking an inactive slot (its lane writes to the
+        scratch page, its attention reads no page, and the rest of it is
+        garbage the caller drops — the batch shape never changes, so
         one executable serves every occupancy).  Returns (next greedy
         token per slot (max_seqs,), logits (tp, max_seqs, V))."""
         b = self.max_seqs
@@ -635,10 +665,13 @@ class ServingEngine:
         positions = np.asarray(positions, np.int64)
         page_idx, offset = self.cache.write_indices(np.arange(b),
                                                     positions)
+        pages_read, pages_table = self._count_pages(positions)
         with trace.region("ompi.engine.decode", "serve:decode_step", "serve",
                           args={"active": int((positions >= 0).sum()),
                                 "slots": b,
-                                "path": "fused" if self.fused else "eager"}
+                                "path": "fused" if self.fused else "eager",
+                                "pages_read": pages_read,
+                                "pages_table": pages_table}
                           if trace.enabled else None):
             with trace.region("ompi.engine.decode.dispatch"):
                 if self.fused:
@@ -657,7 +690,7 @@ class ServingEngine:
                         jnp.asarray(offset),
                         lambda i, q, k, v: _j_paged_attn(
                             q, self.cache.k[i], self.cache.v[i], bt,
-                            pos_dev))
+                            pos_dev, **self._attn_comm))
                     logits, nxt = self._logits(x, b=b)
             with trace.region("ompi.engine.decode.wait"):
                 jax.block_until_ready(nxt)
@@ -672,8 +705,8 @@ class ServingEngine:
         tokens, at consecutive positions (−1 = inactive, whole row).
         All k KV rows are written to the slot's pages FIRST, then the
         flattened (max_seqs·k) batch attends with the causal position
-        mask — within-window causality falls out of ``decode_attention``
-        masking key positions > q_pos.  Returns (greedy next token per
+        mask — within-window causality falls out of the paged attention
+        reading key positions ≤ q_pos only.  Returns (greedy next token per
         window position (max_seqs, k), logits (tp, max_seqs·k, V)).
 
         Rejection is the caller's job: truncate ``cache.seq_lens`` back
@@ -695,10 +728,13 @@ class ServingEngine:
         bt = np.repeat(self.cache.block_tables, k, axis=0)
         flat_tok = np.where(positions >= 0, tokens, 0).reshape(-1)
         flat_pos = positions.reshape(-1)
+        pages_read, pages_table = self._count_pages(flat_pos)
         with trace.region("ompi.engine.decode_window", "serve:decode_window",
                           "serve",
                           args={"active": int((positions[:, 0] >= 0).sum()),
-                                "slots": b, "k": k}
+                                "slots": b, "k": k,
+                                "pages_read": pages_read,
+                                "pages_table": pages_table}
                           if trace.enabled else None):
             if self.fused:
                 logits, nxt = self._decode_step_fused(
@@ -715,7 +751,7 @@ class ServingEngine:
                     jnp.asarray(offset.reshape(-1)),
                     lambda i, q, kk, vv: _j_paged_attn(
                         q, self.cache.k[i], self.cache.v[i], btj,
-                        pos_dev))
+                        pos_dev, **self._attn_comm))
                 logits, nxt = self._logits(x, b=b * k)
             jax.block_until_ready(nxt)
         return (np.asarray(jax.device_get(nxt))[0].reshape(b, k),

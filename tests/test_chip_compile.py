@@ -85,6 +85,38 @@ def test_flash_partials_ring_shard(one_chip):
     assert "%ompi_flash_partials" in hlo
 
 
+@pytest.mark.parametrize("tp", [1, 2], ids=["tp1", "tp2"])
+def test_paged_attention_serve_shape(topo, tp, monkeypatch):
+    """``_j_paged_attn`` at the serve cell's shapes (64 slots x 128 pages
+    of 16 positions, 16 heads x 128 split over tp, bf16 pools): the
+    ``ompi_paged_attn`` kernel compiled to Mosaic, at tp 2 once per chip
+    under the shard_map, with no gather and no pool-sized temporary.
+    The backend here is the CPU, so the test turns the interpreter off."""
+    from ompi_tpu.ops import paged_attention
+    from ompi_tpu.serving.engine import _j_paged_attn
+
+    monkeypatch.setattr(paged_attention, "_default_interpret",
+                        lambda: False)
+
+    if tp == 1:
+        row = rep = SingleDeviceSharding(topo.devices[0])
+        comm = {}
+    else:
+        mesh = Mesh(np.asarray(topo.devices[:tp]), ("tp",))
+        row, rep = NamedSharding(mesh, P("tp")), NamedSharding(mesh, P())
+        comm = {"mesh": mesh, "axis": "tp"}
+    heads, slots, pmax, page = 16 // tp, 64, 128, 16
+    pool = _sds((tp, slots * pmax + 1, page, heads, 128), jnp.bfloat16, row)
+    compiled = _j_paged_attn.lower(
+        _sds((tp, slots, heads, 128), jnp.bfloat16, row), pool, pool,
+        _sds((slots, pmax), jnp.int32, rep), _sds((slots,), jnp.int32, rep),
+        **comm).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"%\S*ompi_paged_attn\S* = .*tpu_custom_call", hlo)
+    assert " gather(" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 24)
+
+
 def test_device_comm_collectives_four_chips(topo):
     from ompi_tpu.op import SUM
     from ompi_tpu.parallel import DeviceComm

@@ -103,6 +103,16 @@ def _greedy_decode(eng, prompt, steps):
     return toks, np.stack(per_step_logits)
 
 
+def _dense_paged_attn(q, kp, vp, bt, q_pos, mesh=None, axis=None):
+    """The dense-gather computation the paged kernel replaced."""
+    k = jnp.take(kp, bt, axis=1)
+    v = jnp.take(vp, bt, axis=1)
+    r, b, pmax, pg, hl, hd = k.shape
+    att = tfm.decode_attention(q, k.reshape(r, b, pmax * pg, hl, hd),
+                               v.reshape(r, b, pmax * pg, hl, hd), q_pos)
+    return att.reshape(r, b, hl * hd)
+
+
 class TestRingSchedule:
     def test_sites_and_wire_pinned(self):
         n, rows, d, isz = 8, 8, CFG.d_model, 4
@@ -251,6 +261,38 @@ class TestSpeculative:
             # the identical greedy stream (checked above) — the
             # truncate rolled seq_lens back, so pages fully released
             assert eng.cache.pages_used == 0
+
+    @pytest.mark.parametrize("n", [1, 2], ids=["tp1", "tp2"])
+    def test_window_matches_dense_gather(self, shared, n, monkeypatch):
+        """A k=3 verify window over two live slots of different lengths
+        and two inactive ones: the kernel's greedy tokens and logits
+        against the dense gather's."""
+        from ompi_tpu.serving import engine as engine_mod
+        _, params, _ = shared
+        dc = _dc(n)
+        sharded = tfm.shard_params(params, dc.mesh, CFG)
+        prompts = [np.arange(5, dtype=np.int32) + 3,
+                   (np.arange(14, dtype=np.int32) * 11) % CFG.vocab]
+
+        def window():
+            eng = _engine(dc, sharded, max_seqs=4)
+            slots = [eng.cache.admit(len(p), 4) for p in prompts]
+            toks = np.zeros((4, 3), np.int32)
+            pos = np.full((4, 3), -1, np.int64)
+            for s, p in zip(slots, prompts):
+                first, _ = eng.prefill(s, p)
+                toks[s] = [first, 7, 42]
+                pos[s] = len(p) + np.arange(3)
+            return eng.decode_window(toks, pos), slots
+
+        (nxt, lg), slots = window()
+        monkeypatch.setattr(engine_mod, "_j_paged_attn", _dense_paged_attn)
+        (ref_nxt, ref_lg), _ = window()
+        np.testing.assert_array_equal(nxt[slots], ref_nxt[slots])
+        rows = np.concatenate([np.arange(3) + 3 * s for s in slots])
+        lg, ref_lg = np.asarray(lg)[0, rows], np.asarray(ref_lg)[0, rows]
+        assert (np.abs(lg - ref_lg).max()
+                / (np.abs(ref_lg).max() + 1e-9)) < 1e-4
 
     def test_spec_k_validation(self, shared):
         dc, _, sharded = shared

@@ -239,6 +239,102 @@ class TestEngineParity:
         assert int(arms) == total
 
 
+def _dense_paged_attn(q, kp, vp, bt, q_pos, mesh=None, axis=None):
+    """The dense-gather computation the kernel replaced: every page of
+    every row's block table gathered, then ``decode_attention``."""
+    k = jnp.take(kp, bt, axis=1)
+    v = jnp.take(vp, bt, axis=1)
+    r, b, pmax, pg, hl, hd = k.shape
+    att = tfm.decode_attention(q, k.reshape(r, b, pmax * pg, hl, hd),
+                               v.reshape(r, b, pmax * pg, hl, hd), q_pos)
+    return att.reshape(r, b, hl * hd)
+
+
+def _batch_decode(eng, prompts, steps):
+    """Prefill ``prompts`` into their own slots, then ``steps`` decode
+    steps over all of them at once (the other slots inactive): greedy
+    tokens (steps, slots) and logits of the live slots."""
+    slots = [eng.cache.admit(len(p), steps + 1) for p in prompts]
+    last = [eng.prefill(s, p)[0] for s, p in zip(slots, prompts)]
+    toks, lgs = [], []
+    for _ in range(steps):
+        t = np.zeros(eng.max_seqs, np.int32)
+        pos = np.full(eng.max_seqs, -1, np.int64)
+        t[slots] = last
+        pos[slots] = eng.cache.seq_lens[slots]
+        nxt, lg = eng.decode_step(t, pos)
+        eng.cache.seq_lens[slots] += 1
+        last = [int(nxt[s]) for s in slots]
+        toks.append(last)
+        lgs.append(np.asarray(lg)[0, slots])
+    for s in slots:
+        eng.cache.release(s)
+    return np.asarray(toks), np.stack(lgs)
+
+
+class TestPagedDecodeAttention:
+    PROMPTS = [np.arange(3, dtype=np.int32) + 5,
+               (np.arange(9, dtype=np.int32) * 7) % CFG.vocab,
+               (np.arange(17, dtype=np.int32) * 13 + 1) % CFG.vocab]
+
+    @pytest.mark.parametrize("n", [1, 2], ids=["tp1", "tp2"])
+    def test_decode_step_matches_dense_gather(self, shared, n,
+                                              monkeypatch):
+        from ompi_tpu.serving import engine as engine_mod
+        _, params, _ = shared
+        dc = _dc(n)
+        sharded = tfm.shard_params(params, dc.mesh, CFG)
+        toks, lg = _batch_decode(_engine(dc, sharded), self.PROMPTS, 6)
+        monkeypatch.setattr(engine_mod, "_j_paged_attn", _dense_paged_attn)
+        ref_toks, ref_lg = _batch_decode(_engine(dc, sharded),
+                                         self.PROMPTS, 6)
+        np.testing.assert_array_equal(toks, ref_toks)
+        assert (np.abs(lg - ref_lg).max()
+                / (np.abs(ref_lg).max() + 1e-9)) < 1e-4
+
+    def test_lowered_attention_gathers_no_pool(self, shared):
+        """No gather in the compiled ``_j_paged_attn`` has an output the
+        size of a page pool; the dense computation's has (the check can
+        see one)."""
+        from ompi_tpu.serving.engine import _j_paged_attn
+        dc = _dc(1)
+        eng = _engine(dc, tfm.shard_params(shared[1], dc.mesh, CFG))
+        kp = eng.cache.k[0]
+        b = eng.max_seqs
+        args = (jnp.zeros((1, b, CFG.n_heads, CFG.head_dim), kp.dtype), kp,
+                eng.cache.v[0], jnp.asarray(eng.cache.block_tables),
+                jnp.zeros(b, jnp.int32))
+
+        def gathered(fn):
+            hlo = fn.lower(*args).compile().as_text()
+            return [int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(",
+                                           hlo)]
+        pool = int(np.prod(kp.shape))
+        assert all(n < pool for n in gathered(_j_paged_attn))
+        assert max(gathered(jax.jit(_dense_paged_attn))) >= pool
+
+    def test_pages_read_counter(self, shared):
+        dc, _, sharded = shared
+        eng = _engine(dc, sharded)
+        page, pmax = eng.cache.page_size, eng.cache.max_pages_per_seq
+        trace.enable()
+        trace.clear()
+        _batch_decode(eng, self.PROMPTS, 3)
+        steps = [e["args"] for e in trace.events()
+                 if e["name"] == "serve:decode_step"]
+        assert len(steps) == 3
+        lens = np.array([len(p) for p in self.PROMPTS])
+        for i, a in enumerate(steps):
+            want = int(sum(-(-(n + i + 1) // page) for n in lens))
+            assert a["pages_read"] == want
+            assert a["pages_table"] == eng.max_seqs * pmax
+        assert eng.attn_pages == {
+            "read": sum(a["pages_read"] for a in steps),
+            "table": 3 * eng.max_seqs * pmax}
+        assert eng.attn_pages["read"] < eng.attn_pages["table"]
+
+
 class TestScheduler:
     def _run(self, shared, policy, n=10, seed=1):
         dc, _, sharded = shared
